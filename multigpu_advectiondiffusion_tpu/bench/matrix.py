@@ -15,6 +15,8 @@ import dataclasses
 import json
 from typing import Optional, Tuple
 
+import jax
+
 from multigpu_advectiondiffusion_tpu.bench.timing import timed_run
 
 # name -> (reference MLUPS, reference source). Single source of truth
@@ -506,11 +508,6 @@ def run_case(
 def main(argv=None):
     import argparse
 
-    from multigpu_advectiondiffusion_tpu.utils.platform_env import (
-        honor_platform_env,
-    )
-
-    honor_platform_env()
 
     ap = argparse.ArgumentParser(prog="multigpu_advectiondiffusion_tpu.bench")
     ap.add_argument("--name", default=None,
@@ -554,17 +551,14 @@ def main(argv=None):
             f"no case {args.name!r}; have "
             f"{[c.name for c in CASES + ENSEMBLE_CASES + PRECISION_CASES]}"
         )
-    from jax.experimental import enable_x64
-
     lines = []
     for case in cases:
-        # x64 scoped per case (jax.experimental.enable_x64 — the
-        # top-level alias was removed): a process-wide flip would poison
+        # x64 scoped per case: a process-wide flip would poison
         # the f32 Pallas rows' Mosaic lowering with i64 constants. The
         # resolved dtype is passed down so the scope and the solver
         # can't diverge.
         dtype = args.dtype or case.dtype
-        with enable_x64(dtype == "float64"):
+        with jax.enable_x64(dtype == "float64"):
             res = run_case(case, dtype=dtype, quick=args.quick,
                            mesh_spec=args.mesh, repeats=args.repeats)
         line = json.dumps(res)

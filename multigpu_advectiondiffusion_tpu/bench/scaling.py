@@ -109,7 +109,7 @@ def scaling_rows(
 
     devices = list(devices if devices is not None else jax.devices())
     if on_tpu is None:
-        on_tpu = devices[0].platform != "cpu"
+        on_tpu = devices[0].platform == "tpu"
     rows = []
     if len(devices) < 2:
         return rows
@@ -197,7 +197,7 @@ def exchange_head_to_head_rows(
 
     devices = list(devices if devices is not None else jax.devices())
     if on_tpu is None:
-        on_tpu = devices[0].platform != "cpu"
+        on_tpu = devices[0].platform == "tpu"
     rows = []
     if len(devices) < 2:
         return rows

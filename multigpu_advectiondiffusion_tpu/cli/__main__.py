@@ -670,11 +670,11 @@ def _resolve_model_argv(argv):
 
 
 def main(argv=None):
-    from multigpu_advectiondiffusion_tpu.utils.platform_env import (
-        honor_platform_env,
+    from multigpu_advectiondiffusion_tpu.utils.compile_cache import (
+        use_compile_cache,
     )
 
-    honor_platform_env()
+    use_compile_cache()
     if argv is None:
         argv = sys.argv[1:]
     argv = _resolve_model_argv(list(argv))

@@ -960,13 +960,8 @@ def _run_solver(
                 / max(1, summary.xla.get("devices", 1))
             )
         if kwargs and is_coord:
-            try:
-                kind = jax.local_devices()[0].device_kind
-            except Exception:
-                kind = None
             calibration.observe(
-                jax.default_backend(), run=name, device_kind=kind,
-                **kwargs,
+                jax.local_devices()[0].device_kind, run=name, **kwargs
             )
 
     if check_error and hasattr(solver, "error_norms"):

@@ -137,17 +137,13 @@ _VMEM_BUDGET = 72 * 1024 * 1024
 
 def _dma_compiler_params():
     """Mosaic params for the in-kernel remote-DMA program: the scoped
-    VMEM ceiling of every slab kernel, plus the collective id (and,
-    where this jax exposes it, the side-effect pin) the cross-chip
-    DMAs require. Only built on the TPU lowering path — interpret mode
-    passes None like every other slab call — so resolve the params
-    class per jax version (``CompilerParams`` today,
-    ``TPUCompilerParams`` on older releases)."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    kwargs = {"vmem_limit_bytes": VMEM_LIMIT, "collective_id": 0}
-    if "has_side_effects" in getattr(cls, "__dataclass_fields__", {}):
-        kwargs["has_side_effects"] = True
-    return cls(**kwargs)
+    VMEM ceiling of every slab kernel, plus the collective id of its
+    neighbor barrier and the side-effect pin the cross-chip DMAs
+    require. Only built on the TPU lowering path — interpret mode
+    passes None like every other slab call."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=VMEM_LIMIT, collective_id=0, has_side_effects=True
+    )
 
 
 def _check_steps_per_exchange(k, sharded: bool, nz: int, G: int) -> int:
@@ -327,7 +323,8 @@ def _pick_dma_block(lz: int, G: int, k: int, viable) -> int | None:
 def _whole_run_dma_kernel(offs, s_in, land_in, ss, land, vs, res, sem_v,
                           sem_w, sem_land, send_sem, recv_sem, *, step_fn,
                           bz: int, G: int, k: int, lz: int, n0: int,
-                          n_iters: int, mesh_axis: str, num_shards: int):
+                          n_iters: int, mesh_axis: str, num_shards: int,
+                          barrier: bool):
     """Sharded whole-run grid with in-kernel neighbor halo exchange.
 
     Grid ``(timestep, z-slab)`` per shard; ``ss`` is the stacked
@@ -433,6 +430,19 @@ def _whole_run_dma_kernel(offs, s_in, land_in, ss, land, vs, res, sem_v,
     def _():
         @pl.when(b == 0)
         def _():
+            if barrier:
+                # neighbor barrier before the first push: a neighbor's
+                # landing buffer is only ours to write once its kernel
+                # has started (XLA may still be initializing it before
+                # then). The CPU interpreter's copies are synchronous
+                # and it has no barrier semaphore.
+                sem = pltpu.get_barrier_semaphore()
+                for nb in (lax.rem(me + 1, P), lax.rem(me + P - 1, P)):
+                    pltpu.semaphore_signal(
+                        sem, 1, device_id=nb,
+                        device_id_type=pltpu.DeviceIdType.LOGICAL,
+                    )
+                pltpu.semaphore_wait(sem, 2)
             up, down = remote_pair(jnp.asarray(0, jnp.int32), read_par)
             up.start()
             down.start()
@@ -799,6 +809,7 @@ class _SlabRunStepper:
             ),
             bz=bz, G=G, k=k, lz=lz, n0=n0, n_iters=num_iters,
             mesh_axis=self.mesh_axis, num_shards=self.num_shards,
+            barrier=not interpret_mode(),
         )
         S = self.embed(u)
         SS = jnp.stack([S, S])

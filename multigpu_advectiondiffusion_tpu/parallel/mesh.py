@@ -10,24 +10,11 @@ or full 3-D blocks — and device placement is XLA's.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import math
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax >= 0.4.35 promoted shard_map out of experimental
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore  # noqa: E501
-
-# the replication checker kwarg was renamed check_rep -> check_vma
-_CHECK_KWARG = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 # Reserved mesh-axis name for the batched ensemble engine's member
 # dimension (ROADMAP item 1: members x devices). The member axis shards
@@ -51,8 +38,8 @@ def shard_map(*args, check: bool = True, **kwargs):
     varying-across-mesh-axes/replication checker — needed only for
     programs containing ``pallas_call`` (whose output avals carry no
     ``vma`` typing); everything else keeps the checker on."""
-    kwargs.setdefault(_CHECK_KWARG, check)
-    return _shard_map(*args, **kwargs)
+    kwargs.setdefault("check_vma", check)
+    return jax.shard_map(*args, **kwargs)
 
 
 def make_mesh(
@@ -61,7 +48,10 @@ def make_mesh(
     """Build a mesh, e.g. ``make_mesh({'dz': 4, 'dy': 2})``.
 
     Axis order follows dict order; total size must divide the device count
-    (or equal it when ``devices`` is None).
+    (or equal it when ``devices`` is None). The axes are ``Auto``: the
+    program places data with explicit ``shard_map`` specs, and arrays
+    stay free of sharding-in-types, so eager ops on a sharded state
+    (``u.at[i].set(v)``) work as on any array.
     """
     names = tuple(axis_sizes)
     sizes = tuple(axis_sizes[n] for n in names)
@@ -70,7 +60,9 @@ def make_mesh(
     need = math.prod(sizes)
     if need > len(devices):
         raise ValueError(f"mesh needs {need} devices, only {len(devices)} available")
-    return jax.make_mesh(sizes, names, devices=tuple(devices[:need]))
+    auto = (jax.sharding.AxisType.Auto,) * len(names)
+    return jax.make_mesh(sizes, names, axis_types=auto,
+                         devices=tuple(devices[:need]))
 
 
 def axis_extent(sizes, name) -> int:

@@ -43,7 +43,7 @@ def initialize(
     3 ``attempts``, ``backoff_seconds`` 2.0 doubling per retry — are
     overridable per call or via ``TPUCFD_DIST_ATTEMPTS`` /
     ``TPUCFD_DIST_BACKOFF`` / ``TPUCFD_DIST_TIMEOUT`` (the last maps to
-    jax's ``initialization_timeout`` where supported). A runtime that is
+    jax's ``initialization_timeout``). A runtime that is
     already initialized is success, not an error (idempotent under the
     supervisor's retry paths).
 
@@ -54,14 +54,10 @@ def initialize(
     import os
     import time
 
-    plats = (
-        os.environ.get("JAX_PLATFORMS", "") or jax.default_backend()
-    )
-    if "cpu" in plats:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older jax: flag absent, gloo is the default
-            pass
+    # the CPU client's cross-process transport; the TPU client ignores
+    # it. Set unconditionally: asking the backend which platform it is
+    # would initialize it before jax.distributed, which must come first.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     if attempts is None:
         attempts = int(os.environ.get("TPUCFD_DIST_ATTEMPTS", "3"))
@@ -77,14 +73,7 @@ def initialize(
         process_id=process_id,
     )
     if timeout_seconds is not None:
-        import inspect
-
-        try:
-            params = inspect.signature(jax.distributed.initialize).parameters
-            if "initialization_timeout" in params:
-                kwargs["initialization_timeout"] = int(timeout_seconds)
-        except (TypeError, ValueError):
-            pass  # unsignaturable wrapper: retry loop carries the policy
+        kwargs["initialization_timeout"] = int(timeout_seconds)
 
     # retry telemetry: every attempt/backoff/outcome is an ordered event
     # in the --metrics stream (the CLI installs the sink BEFORE joining

@@ -151,6 +151,8 @@ def run_serve(args) -> None:
     except LeaseHeldError as err:
         print(f"-- serve: {err}", file=sys.stderr)
         raise SystemExit(EXIT_LEASE_HELD)
+    except ValueError as err:
+        raise SystemExit(f"-- serve: {err}")
     try:
         outcome = sched.serve(until_idle=args.until_idle)
     finally:

@@ -385,6 +385,22 @@ class Scheduler:
                  poll_seconds: float = 0.2, runner=None,
                  aot_cache: bool = True, fsync: bool = True,
                  lease: bool = False, heartbeat_s: float = 2.0):
+        self.max_concurrent = max(1, int(max_concurrent))
+        self.runner = runner if runner is not None else SubprocessRunner()
+        if (self.max_concurrent > 1
+                and isinstance(self.runner, SubprocessRunner)
+                and os.environ.get("JAX_PLATFORMS") != "cpu"):
+            # each job is its own CLI process, and nothing tells it
+            # which chips are its own: every child would claim every
+            # chip, and a chip belongs to one process. (The daemon
+            # itself never initializes a JAX backend, so one child at a
+            # time gets the chips.)
+            raise ValueError(
+                f"max_concurrent={self.max_concurrent} would run that "
+                "many jobs as processes that each claim every chip of "
+                "this host; use max_concurrent=1, or pin the daemon "
+                "and its jobs to the CPU with JAX_PLATFORMS=cpu"
+            )
         self.root = os.path.abspath(root)
         os.makedirs(self.root, exist_ok=True)
         # single-writer lease (ISSUE 20): same mechanism as the request
@@ -407,9 +423,7 @@ class Scheduler:
         )
         if self.aot_dir:
             os.makedirs(self.aot_dir, exist_ok=True)
-        self.max_concurrent = max(1, int(max_concurrent))
         self.poll_seconds = float(poll_seconds)
-        self.runner = runner if runner is not None else SubprocessRunner()
         from multigpu_advectiondiffusion_tpu.telemetry.sink import (
             TelemetrySink,
         )

@@ -1,19 +1,19 @@
 """Measured-peak calibration: persisted demonstrated-capability rates.
 
-The cost model's peak bandwidth/FLOP rates were *assumed* — per-backend
-datasheet defaults, env-overridable (``costmodel.PEAKS``). Every
+The cost model's peak bandwidth/FLOP rates were *assumed* — per-device-kind
+datasheet rows, env-overridable (``costmodel.PEAKS``). Every
 roofline percentage, every tuner pruning decision read against them.
 This store closes the modeled-vs-measured gap the reference closed with
 ``nvprof`` counters: once a run's compiled executables report their own
 XLA bytes/FLOPs (:mod:`telemetry.xprof`), the achieved bandwidth of the
 run's *binding* resource is a measured lower bound on the hardware's
-real, attainable peak — on a tunnel-shared HBM or a thermally limited
-chip, a far more honest pruning denominator than the datasheet number.
+real, attainable peak — on a shared or a thermally limited chip, a
+far more honest pruning denominator than the datasheet number.
 
 Semantics — **demonstrated capability, max-merge**:
 
 * :func:`observe` folds one run's achieved rate into the record for the
-  backend family, keeping the MAX ever observed (a slow run never
+  device kind, keeping the MAX ever observed (a slow run never
   lowers the calibrated peak below a faster earlier one);
 * :func:`lookup` returns the record the cost model consults —
   ``costmodel.peak_rates`` applies it OVER the env-assumed peaks
@@ -91,29 +91,28 @@ def _write(path: str, entries: dict) -> None:
             os.unlink(tmp)
 
 
-def lookup(backend: str, path: Optional[str] = None) -> Optional[dict]:
-    """The calibration record for a backend family (``cpu``/``gpu``/
-    ``tpu``), or ``None`` when absent or the subsystem is disabled.
-    Keys: ``bytes_per_s``/``flops_per_s`` (max observed; either may be
-    absent), ``samples``, ``updated`` (epoch), ``run``/``device_kind``
+def lookup(kind: str, path: Optional[str] = None) -> Optional[dict]:
+    """The calibration record for a ``device_kind`` (``cpu``,
+    ``TPU v5 lite``, ...), or ``None`` when absent or the subsystem is
+    disabled. Keys: ``bytes_per_s``/``flops_per_s`` (max observed;
+    either may be absent), ``samples``, ``updated`` (epoch), ``run``
     provenance of the last improving observation."""
     path = path if path is not None else default_path()
     if not path:
         return None
     with _lock:
-        entry = _read(path).get(backend)
+        entry = _read(path).get(kind)
     return dict(entry) if isinstance(entry, dict) else None
 
 
 def observe(
-    backend: str,
+    kind: str,
     bytes_per_s: Optional[float] = None,
     flops_per_s: Optional[float] = None,
     run: Optional[str] = None,
-    device_kind: Optional[str] = None,
     path: Optional[str] = None,
 ) -> Optional[dict]:
-    """Fold one run's achieved rate(s) into the backend's record
+    """Fold one run's achieved rate(s) into the device kind's record
     (max-merge) and persist atomically; returns the updated record, or
     ``None`` when disabled / nothing to record. Emits one
     ``calib:update`` event (``persisted`` says whether a peak actually
@@ -123,7 +122,7 @@ def observe(
         return None
     with _lock:
         entries = _read(path)
-        entry = dict(entries.get(backend) or {})
+        entry = dict(entries.get(kind) or {})
         improved = False
         for key, val in (("bytes_per_s", bytes_per_s),
                          ("flops_per_s", flops_per_s)):
@@ -137,15 +136,13 @@ def observe(
             entry["updated"] = time.time()
             if run:
                 entry["run"] = run
-            if device_kind:
-                entry["device_kind"] = device_kind
-        entries[backend] = entry
+        entries[kind] = entry
         _write(path, entries)
     from multigpu_advectiondiffusion_tpu import telemetry
 
     telemetry.event(
         "calib", "update",
-        backend=backend,
+        device_kind=kind,
         bytes_per_s=entry.get("bytes_per_s"),
         flops_per_s=entry.get("flops_per_s"),
         samples=entry["samples"],

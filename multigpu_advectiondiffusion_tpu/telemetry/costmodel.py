@@ -44,13 +44,14 @@ f64 bytes):
   combined (read L, u_s, u0; write u) = **3*6 = 18** — an idealized
   lower bound; XLA may fuse better or worse.
 
-Peaks default per backend (env-overridable with
-``TPUCFD_PEAK_BYTES_PER_S`` / ``TPUCFD_PEAK_FLOPS_PER_S``): the TPU row
-is a v5e chip (819 GB/s HBM; 4.92e13 f32 FLOP/s matmul peak — stencil
-code is VPU-bound and will not approach the compute roof, so the
-meaningful number on TPU is the HBM roofline). The CPU row is a nominal
-(50 GB/s, 100 GFLOP/s) placeholder so the plumbing is testable without
-hardware; CPU percentages are not performance claims.
+Peaks are keyed by the device's ``device_kind`` (env-overridable with
+``TPUCFD_PEAK_BYTES_PER_S`` / ``TPUCFD_PEAK_FLOPS_PER_S``); a kind that
+is not in :data:`PEAKS` is an error, not a default. The v5e row is the
+published datasheet (819 GB/s HBM; f32 at a quarter of the 197 TFLOP/s
+bf16 peak — stencil code is VPU-bound and will not approach the compute
+roof, so the meaningful number on TPU is the HBM roofline). The CPU row
+is a nominal (50 GB/s, 100 GFLOP/s) placeholder so the plumbing is
+testable without hardware; CPU percentages are not performance claims.
 
 A *measured* calibration record (``telemetry/calibration.py`` — the
 best achieved rate any run's XLA-reported byte/FLOP counts have
@@ -84,11 +85,12 @@ RK_COMBINE_FLOPS = 5
 # * linear-decay reaction ``- lambda u``: mul + sub = **2**.
 ADR_UPWIND_AXIS_FLOPS = 5
 
-# (peak HBM bytes/s, peak FLOP/s) by backend family
+# (peak HBM bytes/s, peak FLOP/s) by jax ``device_kind``
 PEAKS = {
-    "tpu": (819.0e9, 4.92e13),  # v5e: HBM BW; f32 matmul peak
-    "gpu": (900.0e9, 1.0e13),   # generic placeholder (not measured here)
-    "cpu": (5.0e10, 1.0e11),    # nominal, for plumbing/tests only
+    # Google Cloud documentation, "TPU v5e": 16 GB HBM at 819 GB/s,
+    # 197 TFLOP/s bf16; f32 runs at a quarter of the bf16 rate
+    "TPU v5 lite": (819.0e9, 4.92e13),
+    "cpu": (5.0e10, 1.0e11),  # nominal, for plumbing/tests only
 }
 
 
@@ -203,17 +205,17 @@ EXCHANGE_LATENCY_S = 25e-6
 def halo_exchange_seconds(
     nbytes: float,
     messages: int = 1,
-    backend: Optional[str] = None,
+    kind: Optional[str] = None,
 ) -> float:
     """Modeled wall time of halo traffic: ``messages`` fixed per-message
     latencies (``TPUCFD_EXCHANGE_LATENCY_S`` overrides the default) plus
-    the payload at the backend's peak bandwidth. The communication-
+    the payload at the device's peak bandwidth. The communication-
     avoiding tradeoff in one line: a k-step schedule moves the same
     bytes per step but pays the latency term only once per k steps."""
     lat = float(
         os.environ.get("TPUCFD_EXCHANGE_LATENCY_S", EXCHANGE_LATENCY_S)
     )
-    peak_b, _ = peak_rates(backend)
+    peak_b, _ = peak_rates(kind)
     return messages * lat + (nbytes / peak_b if peak_b else 0.0)
 
 
@@ -228,38 +230,42 @@ def deep_halo_recompute_factor(local_nz: int, G: int, k: int) -> float:
     return 1.0 + (k - 1) * G / float(local_nz)
 
 
-def _backend_family(backend: Optional[str] = None) -> str:
-    if backend is None:
-        try:
-            import jax
+def device_kind(kind: Optional[str] = None) -> str:
+    """``kind``, or the first device's ``device_kind``; raises for a
+    kind with no row in :data:`PEAKS` — a roofline against another
+    chip's peaks would be a wrong number, not an approximate one."""
+    if kind is None:
+        import jax
 
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    return backend if backend in PEAKS else (
-        "tpu" if backend not in ("cpu", "gpu") else backend
-    )
+        kind = jax.devices()[0].device_kind
+    if kind not in PEAKS:
+        raise KeyError(
+            f"no peak rates for device kind {kind!r} (have "
+            f"{sorted(PEAKS)}); add its datasheet row to costmodel.PEAKS"
+        )
+    return kind
 
 
-def peak_rates(backend: Optional[str] = None):
-    """(bytes/s, FLOP/s) peaks for a backend family.
+def peak_rates(kind: Optional[str] = None):
+    """(bytes/s, FLOP/s) peaks for a device kind (default: this
+    process's first device).
 
     Precedence: a *measured* calibration record
     (:mod:`telemetry.calibration` — the max achieved rate any run's XLA
     byte/FLOP counts demonstrated on this rig) beats the env overrides
     (``TPUCFD_PEAK_BYTES_PER_S``/``_FLOPS_PER_S``), which beat the
-    static per-backend defaults. Measured > assumed: set
+    datasheet rows. Measured > assumed: set
     ``TPUCFD_CALIBRATION_PATH=off`` to fall back to assumptions."""
-    info = peak_info(backend)
+    info = peak_info(kind)
     return info["bytes_per_s"], info["flops_per_s"]
 
 
-def peak_info(backend: Optional[str] = None) -> dict:
+def peak_info(kind: Optional[str] = None) -> dict:
     """:func:`peak_rates` plus provenance: where each peak came from
     (``calibrated`` / ``env`` / ``default``) — carried in the tuner's
     ``tune:candidates`` event so a pruning decision is auditable."""
-    family = _backend_family(backend)
-    peak_b, peak_f = PEAKS[family]
+    kind = device_kind(kind)
+    peak_b, peak_f = PEAKS[kind]
     src_b = src_f = "default"
     env_b = os.environ.get("TPUCFD_PEAK_BYTES_PER_S")
     env_f = os.environ.get("TPUCFD_PEAK_FLOPS_PER_S")
@@ -267,19 +273,16 @@ def peak_info(backend: Optional[str] = None) -> dict:
         peak_b, src_b = float(env_b), "env"
     if env_f:
         peak_f, src_f = float(env_f), "env"
-    try:
-        from multigpu_advectiondiffusion_tpu.telemetry import calibration
+    from multigpu_advectiondiffusion_tpu.telemetry import calibration
 
-        cal = calibration.lookup(family)
-    except Exception:
-        cal = None
+    cal = calibration.lookup(kind)
     if cal:
         if cal.get("bytes_per_s"):
             peak_b, src_b = float(cal["bytes_per_s"]), "calibrated"
         if cal.get("flops_per_s"):
             peak_f, src_f = float(cal["flops_per_s"]), "calibrated"
     return {
-        "backend": family,
+        "device_kind": kind,
         "bytes_per_s": peak_b,
         "flops_per_s": peak_f,
         "bytes_source": src_b,
@@ -291,7 +294,7 @@ def roofline(
     cost: StepCost,
     iters: int,
     seconds: float,
-    backend: Optional[str] = None,
+    kind: Optional[str] = None,
     devices: int = 1,
 ) -> dict:
     """Measured seconds vs the model's minimum time on the peak rates.
@@ -302,7 +305,7 @@ def roofline(
     ``bound`` names the binding resource. VMEM-resident rungs (0 modeled
     bytes) are compute-bound by construction.
     """
-    peak_b, peak_f = peak_rates(backend)
+    peak_b, peak_f = peak_rates(kind)
     peak_b *= max(1, devices)
     peak_f *= max(1, devices)
     bytes_total = cost.hbm_bytes * iters
@@ -425,7 +428,7 @@ def summarize_run(
     stepper: str,
     iters: int,
     seconds: float,
-    backend: Optional[str] = None,
+    kind: Optional[str] = None,
 ) -> Optional[dict]:
     """Cost-model block for a finished run: per-step bytes/FLOPs plus
     the roofline efficiency — what ``RunSummary.cost_model`` and the
@@ -436,7 +439,7 @@ def summarize_run(
     devices = 1 if solver.mesh is None else solver.mesh.devices.size
     out = cost.to_dict()
     out["stepper"] = stepper
-    out.update(roofline(cost, iters, seconds, backend=backend,
+    out.update(roofline(cost, iters, seconds, kind=kind,
                         devices=devices))
     return out
 

@@ -147,7 +147,7 @@ EVENT_REGISTRY: Dict[str, Dict[Optional[str], Set[str]]] = {
     # the live-arrays census fallback)
     "mem": {"watermark": {"bytes_in_use", "peak_bytes", "source"}},
     # measured-peak calibration writes (telemetry/calibration.py)
-    "calib": {"update": {"backend", "path", "persisted"}},
+    "calib": {"update": {"device_kind", "path", "persisted"}},
     # checkify sanitizer trips (analysis/sanitizer.py, --checkify): one
     # event per caught NaN/div0/OOB, before SanitizerError enters the
     # supervisor's rollback path

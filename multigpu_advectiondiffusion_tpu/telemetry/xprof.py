@@ -54,7 +54,8 @@ and footprints as first-class outputs of every run):
 ``TPUCFD_XPROF=0`` disables the capture layer (dispatch falls back to
 plain jit); every introspection step is individually fault-tolerant —
 a backend that cannot answer an analysis question degrades that field
-to ``None``/0, never the solve.
+to ``None``/0, never the solve. The solve's own failures are not
+caught here (:class:`_IntrospectedDispatch`).
 """
 
 from __future__ import annotations
@@ -126,6 +127,10 @@ class ExecRecord:
     # state operand (XLA aliases it into the output — no second
     # state-sized HBM buffer per dispatch)
     donated: bool = False
+    # Mosaic kernels in the compiled program (``tpu_custom_call``
+    # sites in its text): 0 means no Pallas kernel runs on the chip —
+    # a generic program, or interpret mode on the CPU
+    kernel_calls: int = 0
 
     def to_fields(self) -> dict:
         return dataclasses.asdict(self)
@@ -197,10 +202,15 @@ class _IntrospectedDispatch:
     arguments (the one compile the jit wrapper would have paid at the
     same moment), capture the executable's cost/memory analyses and
     compile seconds, emit ``xla:cost``, then execute the compiled
-    object — this call and every later one. Any failure on the
-    introspection path falls back permanently to the plain jitted
-    callable, so a Mosaic rejection still surfaces where the kernel
-    ladder expects it and an aval/sharding change simply retraces.
+    object — this call and every later one whose arguments carry the
+    same shapes, dtypes and shardings. A call whose arguments differ
+    falls back permanently to the plain jitted callable, which
+    retraces; a call under a transformation (tracer arguments) goes to
+    it too. Nothing else is caught: a compile failure (a Mosaic
+    rejection) reaches the kernel ladder as raised, and a failure while
+    the executable runs (a device error, an OOM) reaches the caller —
+    re-running it through jit would hide it and, with donation, read a
+    consumed buffer.
     """
 
     def __init__(self, fn, solver, key: str, steps: Optional[int] = None,
@@ -212,6 +222,7 @@ class _IntrospectedDispatch:
         self._aot_key = aot_key
         self._donated = bool(donated)
         self._compiled = None
+        self._signature = None
         self._fallback = False
         self.record: Optional[ExecRecord] = None
 
@@ -278,28 +289,42 @@ class _IntrospectedDispatch:
         return compiled, compile_s, None, None
 
     def __call__(self, *args):
-        if self._fallback:
+        import jax
+
+        if self._fallback or any(isinstance(a, jax.core.Tracer)
+                                 for a in jax.tree.leaves(args)):
+            # under a transformation (jax.grad through a solve) only
+            # the jitted callable can trace
             return self._fn(*args)
+        sig = _signature(args)
         if self._compiled is None:
-            try:
-                compiled, compile_s, aot, saved = self._aot_resolve(args)
-            except Exception:
-                # compile failures must propagate from the PLAIN path:
-                # the kernel ladder classifies them there
-                self._fallback = True
-                return self._fn(*args)
+            compiled, compile_s, aot, saved = self._aot_resolve(args)
             self._compiled = compiled
+            self._signature = sig
             self.record = _capture(
                 compiled, self._solver, self._key, self._steps, compile_s,
                 aot=aot, compile_seconds_saved=saved,
                 donated=self._donated,
             )
-        try:
-            return self._compiled(*args)
-        except Exception:
+        elif self._signature is None:
+            self._signature = sig  # prewarmed from shapes: first call
+        elif sig != self._signature:
             # aval/sharding drift vs the first call: retrace via jit
             self._fallback = True
             return self._fn(*args)
+        return self._compiled(*args)
+
+
+def _signature(args) -> tuple:
+    """What a compiled executable was built for: each argument leaf's
+    shape, dtype and sharding (``None`` for plain Python scalars)."""
+    import jax
+
+    return tuple(
+        (getattr(a, "shape", None), getattr(a, "dtype", type(a)),
+         getattr(a, "sharding", None))
+        for a in jax.tree.leaves(args)
+    )
 
 
 def _capture(compiled, solver, key: str, steps: Optional[int],
@@ -323,6 +348,12 @@ def _capture(compiled, solver, key: str, steps: Optional[int],
         mem = _normalize_memory(compiled.memory_analysis())
     except Exception:
         mem = _normalize_memory(None)
+    try:
+        kernel_calls = compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"'
+        )
+    except Exception:
+        kernel_calls = 0
     stepper = impl = None
     model_bytes = model_flops = None
     devices = 1
@@ -358,6 +389,7 @@ def _capture(compiled, solver, key: str, steps: Optional[int],
             else round(compile_seconds_saved, 6)
         ),
         donated=bool(donated),
+        kernel_calls=kernel_calls,
         **cost,
         **mem,
     )
@@ -513,6 +545,7 @@ def measured_summary(solver, iters: Optional[int] = None,
         "xla_flops_per_step": xla_flops,
         "transcendentals_per_step": rec.transcendentals * devices,
         "peak_bytes": rec.peak_bytes,
+        "kernel_calls": rec.kernel_calls,
         "compile_seconds": round(
             sum(r.compile_seconds for r in recs), 6
         ),
@@ -538,7 +571,7 @@ def measured_summary(solver, iters: Optional[int] = None,
         )
         from multigpu_advectiondiffusion_tpu.telemetry import costmodel
 
-        peak_b, peak_f = costmodel.peak_rates(rec.backend)
+        peak_b, peak_f = costmodel.peak_rates()
         out["peak_gbs"] = round(peak_b * devices / 1e9, 3)
         out["peak_gflops"] = round(peak_f * devices / 1e9, 3)
         if peak_b:

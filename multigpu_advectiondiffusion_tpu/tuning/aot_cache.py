@@ -227,7 +227,10 @@ def load(key: str, args):
         from jax.experimental import serialize_executable as se
 
         blob, in_tree, out_tree = entry["payload"]
-        compiled = se.deserialize_and_load(blob, in_tree, out_tree)
+        compiled = se.deserialize_and_load(
+            blob, in_tree, out_tree,
+            execution_devices=_execution_devices(args),
+        )
     except Exception as exc:  # noqa: BLE001 — stale entry = miss
         _emit("miss", key=key, reason=f"stale: {exc}"[:200], path=path)
         return None
@@ -242,6 +245,20 @@ def load(key: str, args):
         compile_seconds_saved=round(meta["compile_seconds_saved"], 6),
     )
     return compiled, meta
+
+
+def _execution_devices(args) -> list:
+    """The devices the operands live on, in their sharding's order (the
+    first device for abstract operands without one): a deserialized
+    executable otherwise loads onto every device of the backend and
+    refuses a single-device call on a multi-device host."""
+    import jax
+
+    for leaf in jax.tree.leaves(args):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None:
+            return list(sharding._device_assignment)
+    return [jax.devices()[0]]
 
 
 def store(key: str, args, compiled, compile_seconds: float) -> bool:

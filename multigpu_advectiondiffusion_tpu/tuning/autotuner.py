@@ -220,7 +220,8 @@ def candidates(solver_cls, cfg, mesh, decomp) -> list:
 
 
 def modeled_step_seconds(cfg, lshape, cand, devices: int,
-                         backend: str) -> Optional[float]:
+                         device_kind: Optional[str] = None
+                         ) -> Optional[float]:
     """Cost-model seconds for ONE step of one shard under a candidate —
     the pruning metric. None when the model has no opinion (the
     candidate is then never pruned)."""
@@ -242,7 +243,7 @@ def modeled_step_seconds(cfg, lshape, cand, devices: int,
     kwargs = costmodel.solver_cost_kwargs(cfg)
     itemsize = np.dtype(cfg.dtype).itemsize
     cost = costmodel.step_cost(kind, lshape, itemsize, stepper, **kwargs)
-    peak_b, peak_f = costmodel.peak_rates(backend)
+    peak_b, peak_f = costmodel.peak_rates(device_kind)
     t = max(
         cost.hbm_bytes / peak_b if peak_b else 0.0,
         cost.flops / peak_f if peak_f else 0.0,
@@ -259,13 +260,13 @@ def modeled_step_seconds(cfg, lshape, cand, devices: int,
             # one h-deep refresh per RK stage
             h = G // 3
             t += costmodel.halo_exchange_seconds(
-                3 * 2 * h * plane, messages=3, backend=backend
+                3 * 2 * h * plane, messages=3, kind=device_kind
             )
         else:
             # one k*G-deep exchange per k steps: same bytes per step,
             # 1/k of the messages — the comm-avoiding tradeoff
             t += costmodel.halo_exchange_seconds(
-                2 * G * plane, messages=1.0 / k, backend=backend
+                2 * G * plane, messages=1.0 / k, kind=device_kind
             )
     return t
 
@@ -403,7 +404,7 @@ def autotune(solver_cls, cfg, mesh, decomp, cache: TuningCache, key: str,
     cands = candidates(solver_cls, cfg, mesh, decomp)
     best_model = None
     for c in cands:
-        t = modeled_step_seconds(cfg, lshape, c, devices, backend)
+        t = modeled_step_seconds(cfg, lshape, c, devices)
         c["modeled_us"] = None if t is None else round(t * 1e6, 3)
         if t is not None and (best_model is None or t < best_model):
             best_model = t
@@ -423,7 +424,7 @@ def autotune(solver_cls, cfg, mesh, decomp, cache: TuningCache, key: str,
         # these rates — "calibrated" means a measured peak
         # (telemetry/calibration.py) replaced the env/default
         # assumption, i.e. the tuner pruned with measured numbers
-        peaks=costmodel.peak_info(backend),
+        peaks=costmodel.peak_info(),
         considered=[
             {k: c.get(k) for k in ("impl", "steps_per_exchange",
                                    "exchange", "modeled_us", "pruned")}

@@ -11,7 +11,8 @@ WITHOUT timing anything, just by building each row's solver and asking
 import importlib.util
 import os
 
-from jax.experimental import enable_x64
+import jax
+
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -34,7 +35,7 @@ def test_bench_rows_engage_expected_steppers():
     assert len(rows) >= 13
     seen = {}
     for metric, make_solver, mode, work, baseline, expect in rows:
-        with enable_x64(metric.endswith("_f64_mlups")):
+        with jax.enable_x64(metric.endswith("_f64_mlups")):
             solver = make_solver()
             engaged = solver.engaged_path(
                 "t_end" if mode == "t_end" else "iters"
@@ -73,7 +74,7 @@ def test_bench_matrix_cases_report_engaged():
         grid_xyz = tuple(
             max(16, g // case.quick_scale) for g in case.grid_xyz
         )
-        with enable_x64(dtype == "float64"):
+        with jax.enable_x64(dtype == "float64"):
             solver = build_solver(case, dtype, grid_xyz, None)
             engaged = solver.engaged_path()["stepper"]
         impl = resolve_impl(case, dtype)

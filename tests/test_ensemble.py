@@ -42,6 +42,8 @@ from multigpu_advectiondiffusion_tpu.resilience.errors import (
 )
 from multigpu_advectiondiffusion_tpu.tuning import aot_cache
 
+from f32_bound import assert_f32_close
+
 
 @pytest.fixture(autouse=True)
 def _isolate_aot_cache(monkeypatch):
@@ -67,17 +69,23 @@ def _members(B):
     ]
 
 
-def _assert_bit_exact(es, B, iters):
+def _assert_bit_exact(es, B, iters, ulps=0):
+    """Every member equals its looped single run: bit for bit, or
+    within ``ulps`` f32 ulps where the batched program is a different
+    fused kernel program (``f32_bound``)."""
     est = es.initial_state()
     out = es.run(est, iters)
     assert isinstance(out, EnsembleState) and out.members == B
     for i in range(B):
         ms = es.member_solver(i)
         ref = ms.run(ms.initial_state(), iters)
-        np.testing.assert_array_equal(
-            np.asarray(out.u[i]), np.asarray(ref.u),
-            err_msg=f"member {i} diverged from its looped single run",
-        )
+        what = f"member {i} diverged from its looped single run"
+        if ulps:
+            assert_f32_close(out.u[i], ref.u, ulps, what)
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(out.u[i]), np.asarray(ref.u), err_msg=what,
+            )
         assert float(out.t[i]) == float(ref.t)
     return out
 
@@ -96,7 +104,7 @@ def test_batched_b8_bit_exact_fused_stage_diffusion():
     cfg = DiffusionConfig(grid=g, diffusivity=1.0, dtype="float32",
                           impl="pallas_stage")
     es = EnsembleSolver(DiffusionSolver, cfg, 8)
-    _assert_bit_exact(es, 8, 2)
+    _assert_bit_exact(es, 8, 2, ulps=4)
     assert es.engaged_path()["stepper"] == "ensemble-vmap[fused-stage]"
 
 

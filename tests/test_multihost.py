@@ -179,10 +179,12 @@ for name, cfg_cls, solver_cls, kw, tol in (
 # (MultiGPU/*/run.sh) ----
 ulp = 32 * np.finfo(np.float32).eps
 fused_cases = (
-    # serialized refresh + global wall offsets (diffusion is bitwise)
+    # serialized refresh + global wall offsets (diffusion: the same
+    # arithmetic, up to XLA-CPU's per-program FMA contraction — the
+    # 4-ulp bound of tests/f32_bound.py)
     ("diffusion3d-fused", DiffusionSolver,
      DiffusionConfig(grid=grid, dtype="float32", impl="pallas"),
-     decomp, 0.0, False),
+     decomp, 4 * np.finfo(np.float32).eps, False),
     # adaptive dt: the pmax wave-speed reduction crosses processes
     ("burgers3d-fused-adaptive", BurgersSolver,
      BurgersConfig(grid=grid, dtype="float32", nu=1e-5, impl="pallas"),

@@ -5,43 +5,22 @@ on send streams while the interior RHS runs on the compute stream
 (``MultiGPU/Diffusion3d_Baseline/main.c:203-260``). The rebuild's
 ``overlap="split"`` schedule claims the same property via dataflow: the
 interior stencil must not depend on the in-flight ``ppermute`` ghosts,
-so XLA's async collective scheduler can run both concurrently. Two
-checks, strongest-available per environment:
-
-1. Dataflow independence (any backend): poison the exchanged ghost
-   slabs with NaN — the interior output cells must stay finite, proving
-   the interior computation consumes no ghost data (the precondition
-   for overlap; a dependency would serialize it).
-2. TPU instruction schedule (AOT, no chips needed): compile the sharded
-   split-overlap step against a multi-chip v5e topology
-   (``jax.experimental.topologies``) and assert the compiled module
-   issues ``collective-permute-start``, schedules compute fusions, and
-   only then waits on ``collective-permute-done`` — the overlap as the
-   TPU compiler actually scheduled it, the machine-checked analog of
-   reading the five-stream choreography out of an nvprof trace
-   (``profile.sh``).
+so XLA's async collective scheduler can run both concurrently. This
+file checks the precondition on any backend: poison the exchanged ghost
+slabs with NaN — the interior output cells must stay finite, proving
+the interior computation consumes no ghost data (a dependency would
+serialize it). The overlap as the TPU compiler actually schedules it is
+read out of compiled v5e programs in ``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
 
-import re
-
 import numpy as np
-import pytest
-
-import aot_utils
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
-from multigpu_advectiondiffusion_tpu import (
-    DiffusionConfig,
-    DiffusionSolver,
-    Grid,
-)
 from multigpu_advectiondiffusion_tpu.ops.stencils import split_axis_apply
-from multigpu_advectiondiffusion_tpu.parallel.mesh import Decomposition
 
 
 def test_split_schedule_interior_is_ghost_independent():
@@ -65,349 +44,3 @@ def test_split_schedule_interior_is_ghost_independent():
     assert all(np.isnan(e).all() for e in edges), (
         "boundary bands should be exactly the ghost-dependent region"
     )
-
-
-def _schedule_events(txt, extra=()):
-    """(line, kind) events of a compiled module's entry schedule: async
-    collective-permute starts/dones, compute fusions, and any extra
-    (pattern, kind) pairs — text order == schedule order."""
-    events = []
-    pats = [
-        (r"= .*collective-permute-start", "start"),
-        (r"= .*collective-permute-done", "done"),
-        (r"= .*fusion\(", "fusion"),
-        *extra,
-    ]
-    for i, line in enumerate(txt.splitlines()):
-        ls = line.strip()
-        for pat, kind in pats:
-            if re.search(pat, ls):
-                events.append((i, kind))
-                break
-    return events
-
-
-def _count_in_windows(events, kind):
-    starts = [i for i, k in events if k == "start"]
-    dones = [i for i, k in events if k == "done"]
-    n = 0
-    for s in starts:
-        d = min((d for d in dones if d > s), default=None)
-        if d is None:
-            continue
-        n += sum(1 for i, k in events if k == kind and s < i < d)
-    return n, bool(starts and dones)
-
-
-@pytest.mark.slow
-def test_split_overlap_tpu_schedule_hides_collectives():
-    """AOT-compile the sharded ``overlap='split'`` diffusion step for a
-    4-chip v5e topology and read the overlap out of the compiled
-    module's schedule: compute fusions must sit between a
-    ``collective-permute-start`` and its ``collective-permute-done``."""
-    topo = aot_utils.get_aot_topology("v5e:2x2")
-
-    from jax.sharding import Mesh
-
-    devs = np.asarray(topo.devices[:4])
-    mesh = Mesh(devs, ("dz",))
-    grid = Grid.make(128, 128, 128, lengths=2.0)
-    solver = DiffusionSolver(
-        DiffusionConfig(grid=grid, dtype="float32", overlap="split"),
-        mesh=mesh,
-        decomp=Decomposition.slab("dz"),
-    )
-    f = solver._wrap(solver._local_step)
-    u = jax.ShapeDtypeStruct(grid.shape, jnp.float32,
-                             sharding=solver.sharding())
-    t = jax.ShapeDtypeStruct((), jnp.float32)
-    txt = f.lower(u, t).compile().as_text()
-
-    # entry-computation schedule order == text order within the module
-    events = _schedule_events(txt)
-    overlapped, have_pairs = _count_in_windows(events, "fusion")
-    assert have_pairs, "expected async collective-permute pairs"
-    assert overlapped > 0, (
-        "no compute scheduled inside a collective-permute window — "
-        "the split overlap is not being hidden"
-    )
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("model", ["burgers", "diffusion",
-                                   "burgers-pencil", "burgers-xghost"])
-def test_fused_split_overlap_tpu_schedule_hides_collectives(
-    monkeypatch, model
-):
-    """The fused split-overlap schedules, AOT-compiled for a 4-chip v5e
-    topology with the real Mosaic kernels (interpret mode forced off):
-    the interior stage kernel — a ``tpu_custom_call`` — must be
-    scheduled between a ``collective-permute-start`` and its ``-done``,
-    i.e. the tuned kernel runs while the z-halo rides the ICI, which is
-    what the reference's five-stream choreography exists for
-    (MultiGPU/Diffusion3d_Baseline/main.c:203-260, Kernels.cu:207-261).
-    """
-    topo = aot_utils.get_aot_topology("v5e:2x2")
-
-    from jax.sharding import Mesh
-
-    from multigpu_advectiondiffusion_tpu import BurgersConfig, BurgersSolver
-    from multigpu_advectiondiffusion_tpu.ops.pallas import (
-        fused_burgers as fb,
-        fused_diffusion as fd,
-        laplacian as lap,
-    )
-
-    # force real Mosaic lowering (the CPU-pinned test env defaults to
-    # interpret mode, which would compile plain fusions instead)
-    monkeypatch.setattr(fb, "interpret_mode", lambda: False)
-    monkeypatch.setattr(fd, "interpret_mode", lambda: False)
-    monkeypatch.setattr(lap, "interpret_mode", lambda: False)
-
-    devs = np.asarray(topo.devices[:4])
-    if model == "burgers-pencil":
-        mesh = Mesh(devs.reshape(2, 2), ("dz", "dy"))
-    elif model == "burgers-xghost":
-        mesh = Mesh(devs.reshape(2, 2), ("dz", "dx"))
-    else:
-        mesh = Mesh(devs, ("dz",))
-    # x64 (the suite default) poisons Mosaic verification with i64
-    # constants — the kernels are f32/i32 by design
-    with enable_x64(False):
-        if model == "burgers":
-            # local lz = 32 -> bz=8 -> n_bz=4: a real interior band
-            grid = Grid.make(128, 16, 128, lengths=2.0)
-            solver = BurgersSolver(
-                BurgersConfig(grid=grid, nu=1e-5, dtype="float32",
-                              adaptive_dt=False, impl="pallas",
-                              overlap="split"),
-                mesh=mesh,
-                decomp=Decomposition.slab("dz"),
-            )
-        elif model == "burgers-pencil":
-            # {dz, dy} pencil: local (64, 8, 128) — the z halo rides the
-            # overlapped exchanged-slab schedule, y a serialized refresh
-            grid = Grid.make(128, 16, 128, lengths=2.0)
-            solver = BurgersSolver(
-                BurgersConfig(grid=grid, nu=1e-5, dtype="float32",
-                              adaptive_dt=False, impl="pallas",
-                              overlap="split"),
-                mesh=mesh,
-                decomp=Decomposition.of({0: "dz", 1: "dy"}),
-            )
-        elif model == "burgers-xghost":
-            # {dz, dx}: the stored-x-ghost layout (interior at lane
-            # offset r) through REAL Mosaic lowering — the CPU interpret
-            # tests can't validate this layout's Mosaic compile — with
-            # the z exchange overlapped and the x refresh serialized
-            grid = Grid.make(128, 16, 128, lengths=2.0)
-            solver = BurgersSolver(
-                BurgersConfig(grid=grid, nu=1e-5, dtype="float32",
-                              adaptive_dt=False, impl="pallas",
-                              overlap="split"),
-                mesh=mesh,
-                decomp=Decomposition.of({0: "dz", 2: "dx"}),
-            )
-            assert solver._fused_stepper().x_sharded
-        else:
-            # local lz = 60 -> bz=20 -> n_bz=3
-            grid = Grid.make(128, 16, 240, lengths=2.0)
-            solver = DiffusionSolver(
-                DiffusionConfig(grid=grid, dtype="float32",
-                                impl="pallas", overlap="split"),
-                mesh=mesh,
-                decomp=Decomposition.slab("dz"),
-            )
-        fused = solver._fused_stepper()
-        assert fused is not None and fused.overlap_split
-        refresh, offsets_fn, exch = solver._fused_sharded_ctx(fused)
-        assert exch is not None
-        # pencil/x-sharded meshes carry a serialized non-z refresh
-        # alongside the overlapped z exchange; pure slabs have none
-        assert (refresh is not None) == (
-            model in ("burgers-pencil", "burgers-xghost")
-        )
-
-        def block(u, t):
-            kw = {"exch": exch}
-            if refresh is not None:
-                kw["refresh"] = refresh
-            if offsets_fn is not None and model == "diffusion":
-                kw["offsets"] = offsets_fn()
-            return fused.run(u, t, 2, **kw)
-
-        f = solver._wrap(block)
-        u = jax.ShapeDtypeStruct(grid.shape, jnp.float32,
-                                 sharding=solver.sharding())
-        t = jax.ShapeDtypeStruct((), jnp.float32)
-        try:
-            txt = f.lower(u, t).compile().as_text()
-        except Exception as e:  # Mosaic AOT unavailable on this rig
-            aot_utils.aot_unavailable(
-                f"Mosaic AOT compile unavailable: {type(e).__name__}: {e}"
-            )
-
-    events = _schedule_events(
-        txt, extra=[(r"= .*custom-call.*tpu_custom_call", "kernel")]
-    )
-    kernels_in, have_pairs = _count_in_windows(events, "kernel")
-    fusions_in, _ = _count_in_windows(events, "fusion")
-    assert have_pairs, "expected async collective-permute pairs"
-    assert kernels_in + fusions_in > 0, (
-        "no stage kernel or fusion scheduled inside a collective-permute "
-        "window — the fused split overlap is not being hidden"
-    )
-    # the serialized path has zero kernels in windows by construction;
-    # demand the actual Mosaic stage kernel in at least one window
-    assert kernels_in > 0, (
-        "fusions but no tpu_custom_call inside the permute windows — "
-        "the interior stage kernel is still serialized with the exchange"
-    )
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("overlap", ["padded", "split"])
-@pytest.mark.parametrize("model", ["burgers", "diffusion",
-                                   "burgers-weno7"])
-def test_fused2d_sharded_mosaic_aot_compiles(monkeypatch, model, overlap):
-    """The sharded 2-D per-stage steppers (whole-shard VMEM kernels +
-    ppermute ghost refresh, or the three-band split-overlap schedule)
-    must compile through the real Mosaic pipeline for a 4-chip v5e
-    topology — the interpret-mode suite can't catch Mosaic-only lowering
-    rejections (alignment, memory-space, aliasing constraints). For
-    overlap='split' the compiled schedule must place a stage kernel
-    inside a collective-permute window — the ghost-independent interior
-    band actually hides the exchange."""
-    topo = aot_utils.get_aot_topology("v5e:2x2")
-
-    from jax.sharding import Mesh
-
-    from multigpu_advectiondiffusion_tpu import BurgersConfig, BurgersSolver
-    from multigpu_advectiondiffusion_tpu.ops.pallas import (
-        fused2d_sharded as f2s,
-        fused_burgers as fb,
-        fused_diffusion as fd,
-        laplacian as lap,
-    )
-
-    for mod in (f2s, fb, fd, lap):
-        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
-
-    devs = np.asarray(topo.devices[:4])
-    mesh = Mesh(devs, ("dy",))
-    with enable_x64(False):
-        grid = Grid.make(256, 256, lengths=2.0)
-        if model == "burgers":
-            solver = BurgersSolver(
-                BurgersConfig(grid=grid, nu=1e-4, dtype="float32",
-                              impl="pallas", overlap=overlap),
-                mesh=mesh,
-                decomp=Decomposition.of({0: "dy"}),
-            )
-        elif model == "burgers-weno7":
-            # order 7 (halo-4 bands) through real Mosaic lowering
-            solver = BurgersSolver(
-                BurgersConfig(grid=grid, weno_order=7, nu=1e-4,
-                              dtype="float32", impl="pallas",
-                              overlap=overlap),
-                mesh=mesh,
-                decomp=Decomposition.of({0: "dy"}),
-            )
-        else:
-            solver = DiffusionSolver(
-                DiffusionConfig(grid=grid, dtype="float32", impl="pallas",
-                                overlap=overlap),
-                mesh=mesh,
-                decomp=Decomposition.of({0: "dy"}),
-            )
-        fused = solver._fused_stepper()
-        assert fused is not None and fused.sharded
-        assert fused.overlap_split == (overlap == "split")
-        if model == "burgers-weno7":
-            assert fused.halo == 4
-        refresh, offsets_fn, exch = solver._fused_sharded_ctx(fused)
-
-        def block(u, t):
-            return fused.run(u, t, 2, refresh=refresh,
-                             offsets=offsets_fn(), exch=exch)
-
-        f = solver._wrap(block)
-        u = jax.ShapeDtypeStruct(grid.shape, jnp.float32,
-                                 sharding=solver.sharding())
-        t = jax.ShapeDtypeStruct((), jnp.float32)
-        try:
-            txt = f.lower(u, t).compile().as_text()
-        except Exception as e:  # Mosaic AOT unavailable on this rig
-            aot_utils.aot_unavailable(
-                f"Mosaic AOT compile unavailable: {type(e).__name__}: {e}"
-            )
-
-    assert "tpu_custom_call" in txt, "stage kernels did not lower via Mosaic"
-    assert "collective-permute" in txt, "ghost refresh lost its ppermute"
-    if overlap == "split":
-        events = _schedule_events(
-            txt, extra=[(r"= .*custom-call.*tpu_custom_call", "kernel")]
-        )
-        kernels_in, have_pairs = _count_in_windows(events, "kernel")
-        assert have_pairs, "expected async collective-permute pairs"
-        assert kernels_in > 0, (
-            "no stage kernel scheduled inside a collective-permute "
-            "window — the 2-D split overlap is not being hidden"
-        )
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("model", ["diffusion", "burgers"])
-def test_fused_slab_run_mosaic_aot_compiles(monkeypatch, model):
-    """The slab-pipelined whole-run stepper (single Pallas program over
-    a (timestep, z-slab) grid with the stacked ping-pong state) must
-    compile through the real Mosaic pipeline for a v5e target — the
-    interpret-mode suite can't catch Mosaic-only rejections of the
-    dynamically-indexed stacked-buffer DMAs."""
-    topo = aot_utils.get_aot_topology("v5e:2x2")
-
-    from multigpu_advectiondiffusion_tpu import BurgersConfig, BurgersSolver
-    from multigpu_advectiondiffusion_tpu.ops.pallas import (
-        fused_burgers as fb,
-        fused_diffusion as fd,
-        fused_slab_run as fsr,
-        laplacian as lap,
-    )
-
-    for mod in (fsr, fb, fd, lap):
-        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
-
-    with enable_x64(False):
-        if model == "diffusion":
-            grid = Grid.make(128, 128, 64, lengths=2.0)
-            solver = DiffusionSolver(
-                DiffusionConfig(grid=grid, dtype="float32",
-                                impl="pallas_slab")
-            )
-        else:
-            grid = Grid.make(128, 64, 64, lengths=2.0)
-            solver = BurgersSolver(
-                BurgersConfig(grid=grid, nu=1e-5, dtype="float32",
-                              adaptive_dt=False, impl="pallas_slab")
-            )
-        fused = solver._fused_stepper()
-        assert fused is not None, getattr(solver, "_fused_fallback", None)
-        assert fused.engaged_label == "fused-whole-run-slab"
-        assert fused.n_slabs >= 2, "want a multi-slab pipeline"
-
-        def block(u, t):
-            return fused.run(u, t, 3)
-
-        # unsharded: pin the AOT lowering to one device of the TPU
-        # topology via the operands' sharding
-        sharding = jax.sharding.SingleDeviceSharding(topo.devices[0])
-        u = jax.ShapeDtypeStruct(grid.shape, jnp.float32, sharding=sharding)
-        t = jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding)
-        try:
-            txt = jax.jit(block).lower(u, t).compile().as_text()
-        except Exception as e:  # Mosaic AOT unavailable on this rig
-            aot_utils.aot_unavailable(
-                f"Mosaic AOT compile unavailable: {type(e).__name__}: {e}"
-            )
-
-    assert "tpu_custom_call" in txt, "slab kernel did not lower via Mosaic"

@@ -600,3 +600,18 @@ def test_sched_events_validate_and_render_timeline(tmp_path):
     text = report.format_text()
     assert "job queue timeline" in text
     assert "retry [diverged]" in text
+
+
+def test_concurrent_job_processes_refused_off_the_cpu(tmp_path,
+                                                      monkeypatch):
+    """Each job is its own CLI process that would claim every chip of
+    the host; more than one at a time is refused unless the jobs are
+    pinned to the CPU."""
+    from multigpu_advectiondiffusion_tpu.service.daemon import Scheduler
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(ValueError, match="claim every chip"):
+        Scheduler(str(tmp_path / "a"), max_concurrent=2)
+    Scheduler(str(tmp_path / "b"), max_concurrent=1).close()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    Scheduler(str(tmp_path / "c"), max_concurrent=2).close()

@@ -23,6 +23,8 @@ from multigpu_advectiondiffusion_tpu import (
     Grid,
 )
 
+from f32_bound import assert_f32_close
+
 _ULPS = 32 * np.finfo(np.float32).eps
 
 
@@ -186,8 +188,9 @@ def test_slab_sharded_zslab_split_matches_unsharded(devices):
     exchange per step. overlap='split' runs the three-call schedule
     (interior slabs concurrent with the in-flight ppermute; the two
     edge calls consume the exchanged G-slabs) and must match the
-    unsharded whole-run stepper — diffusion bit-for-bit (identical
-    per-cell op sequence), Burgers to the interpret-mode ulp bound."""
+    unsharded whole-run stepper — diffusion to a few f32 ulps (the same
+    per-cell op sequence, up to XLA-CPU's per-program FMA contraction),
+    Burgers to the interpret-mode ulp bound."""
     from multigpu_advectiondiffusion_tpu.parallel.mesh import (
         Decomposition,
         make_mesh,
@@ -211,7 +214,7 @@ def test_slab_sharded_zslab_split_matches_unsharded(devices):
     assert f.engaged_label == "fused-whole-run-slab"
     assert s.engaged_path()["overlap"] == "split"
     out = s.run(s.initial_state(), 4)
-    assert float(jnp.max(jnp.abs(out.u - ref.u))) == 0.0
+    assert_f32_close(out.u, ref.u, what="split sharded vs unsharded slab")
     assert float(out.t) == float(ref.t)
 
     # burgers: local lz=30 -> split picks bz=10 (>= G=9), n_slabs=3
@@ -450,7 +453,7 @@ def test_slab_dma_mosaic_rejection_degrades_to_split(devices, monkeypatch):
     assert chain == [
         ("fused-whole-run-slab[dma]", "fused-whole-run-slab[split]")
     ]
-    assert float(jnp.max(jnp.abs(out.u - ref.u))) == 0.0
+    assert_f32_close(out.u, ref.u, what="degraded split vs padded slab")
 
 
 def test_slab_sharded_declines_off_design(devices):

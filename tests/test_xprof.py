@@ -126,6 +126,39 @@ def test_dispatch_capture_reuses_one_compile_per_program():
     assert entry._compiled is not None and not entry._fallback
 
 
+def test_dispatch_retraces_only_on_argument_drift():
+    """A call whose arguments differ in shape, dtype or sharding from
+    the compiled program's retraces through jit; a failure while the
+    compiled program runs is the caller's — never re-run through jit,
+    which would hide a device error and re-read a donated buffer."""
+    solver = _diffusion3d(impl="xla")
+    entry = xprof.wrap_dispatch(jax.jit(lambda u: u * 2.0), solver, "k")
+    x = jnp.ones((4, 4), jnp.float32)
+    assert float(entry(x).sum()) == 32.0
+    assert float(entry(jnp.ones((2, 4), jnp.float32)).sum()) == 16.0
+    assert entry._fallback  # shape drift: plain jit from here on
+
+    entry = xprof.wrap_dispatch(jax.jit(lambda u: u * 2.0), solver, "k2")
+    entry(x)
+
+    def device_error(*args):
+        raise RuntimeError("simulated device error")
+
+    entry._compiled = device_error
+    with pytest.raises(RuntimeError, match="simulated device error"):
+        entry(x)
+    assert not entry._fallback
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """The v5e row is the datasheet's; a kind with no row is an error,
+    not another chip's peaks."""
+    assert costmodel.PEAKS["TPU v5 lite"][0] == 819.0e9
+    assert costmodel.peak_info()["device_kind"] == "cpu"
+    with pytest.raises(KeyError, match="TPU v9"):
+        costmodel.peak_rates("TPU v9")
+
+
 def test_xprof_disabled_falls_back_to_plain_jit(monkeypatch):
     monkeypatch.setenv("TPUCFD_XPROF", "0")
     solver = _diffusion3d(impl="xla")
