@@ -199,9 +199,14 @@ def _add_common(p: argparse.ArgumentParser, ndim: int):
                         "the nvprof wrapping of profile.sh, TPU-style")
     p.add_argument("--trace", dest="profile", metavar="DIR",
                    help="alias for --profile: the captured trace carries "
-                        "the whole rung hierarchy as labeled spans "
-                        "(tpucfd.run[<stepper>], tpucfd.halo_exchange_*, "
-                        "tpucfd.<rung> step bodies) viewable in Perfetto")
+                        "the dispatch layer and the rung hierarchy as "
+                        "labeled spans (tpucfd.dispatch per driver call "
+                        "with its steps/cache/programs stats, and its "
+                        "children tpucfd.dispatch.resolve|execute|state "
+                        "and tpucfd.run[<stepper>]; tpucfd.compile and "
+                        "tpucfd.introspect on a program's first call; "
+                        "tpucfd.halo_exchange_*, tpucfd.<rung> step "
+                        "bodies) viewable in Perfetto")
     p.add_argument("--metrics", default=None, metavar="PATH",
                    help="stream structured telemetry to PATH as JSONL: "
                         "span/counter events from dispatch and halo "

@@ -52,6 +52,13 @@ from multigpu_advectiondiffusion_tpu.parallel.mesh import (
 )
 from multigpu_advectiondiffusion_tpu.timestepping.integrators import INTEGRATORS
 from multigpu_advectiondiffusion_tpu.utils.ic import initial_condition
+from multigpu_advectiondiffusion_tpu.utils.profiling import (
+    EXECUTE_SPAN,
+    RESOLVE_SPAN,
+    STATE_SPAN,
+    annotate,
+    dispatch,
+)
 
 
 def _consume_donated(*arrays) -> None:
@@ -103,6 +110,10 @@ class LocalPhysics:
 
 
 class SolverBase:
+    # the innermost open tpucfd.dispatch span (_dispatch), which
+    # _compiled tells of each program it hands out
+    _open_dispatch = None
+
     def __init__(self, cfg, mesh=None, decomp: Decomposition | None = None):
         self.cfg = cfg
         self.mesh = mesh
@@ -584,6 +595,8 @@ class SolverBase:
             key = (*key, "donated") if isinstance(key, tuple) else (
                 key, "donated"
             )
+        if self._open_dispatch is not None:
+            self._open_dispatch.program(built=key not in self._cache)
         if key not in self._cache:
             from multigpu_advectiondiffusion_tpu import telemetry
             from multigpu_advectiondiffusion_tpu.telemetry import xprof
@@ -616,6 +629,20 @@ class SolverBase:
             )
         return self._cache[key]
 
+    @contextlib.contextmanager
+    def _dispatch(self, op: str, steps=None):
+        """The top-level ``tpucfd.dispatch`` span of one public driver
+        call (:class:`~multigpu_advectiondiffusion_tpu.utils.profiling.
+        dispatch`), opened before anything else the call does;
+        :meth:`_compiled` counts on it the programs the call invokes."""
+        span = dispatch(op, steps)
+        outer, self._open_dispatch = self._open_dispatch, span
+        try:
+            with span:
+                yield
+        finally:
+            self._open_dispatch = outer
+
     def _dispatch_span(self, op: str, mode: str = "iters", **fields):
         """Context labeling one public driver call with the engaged
         rung: a ``jax.profiler.TraceAnnotation`` (so ``--trace``
@@ -624,9 +651,9 @@ class SolverBase:
         installed, a structured ``solver.<op>`` span carrying the
         engaged stepper/impl/overlap."""
         from multigpu_advectiondiffusion_tpu import telemetry
-        from multigpu_advectiondiffusion_tpu.utils.profiling import annotate
 
-        eng = self.engaged_path(mode=mode)
+        with annotate(RESOLVE_SPAN):
+            eng = self.engaged_path(mode=mode)
         stack = contextlib.ExitStack()
         stack.enter_context(annotate(f"tpucfd.{op}[{eng['stepper']}]"))
         sink = telemetry.get_sink()
@@ -739,15 +766,30 @@ class SolverBase:
     # Public drivers
     # ------------------------------------------------------------------ #
     def step(self, state: SolverState) -> SolverState:
-        def call():
-            with self._dispatch_span("step"):
-                f = self._compiled(
-                    "step", lambda: self._wrap(self._local_step), steps=1
-                )
-                u, t = f(state.u, state.t)
-                return SolverState(u=u, t=t, it=state.it + 1)
+        with self._dispatch("step", steps=1):
+            def call():
+                with self._dispatch_span("step"):
+                    with annotate(RESOLVE_SPAN):
+                        f = self._compiled(
+                            "step", lambda: self._wrap(self._local_step),
+                            steps=1,
+                        )
+                    return self._finish(
+                        f, (state.u, state.t),
+                        lambda u, t: SolverState(u=u, t=t, it=state.it + 1),
+                    )
 
-        return self._with_ladder(call)
+            return self._with_ladder(call)
+
+    @staticmethod
+    def _finish(f, args, build):
+        """The end of every public driver: call the resolved program
+        ``f`` on ``args`` (asynchronous: back before the device runs it) and
+        build the returned state from its outputs, each in its span."""
+        with annotate(EXECUTE_SPAN):
+            out = f(*args)
+        with annotate(STATE_SPAN):
+            return build(*out)
 
     def _fused_stepper(self, mode: str = "iters"):
         """Solver-specific fully-fused fast path, or ``None`` (generic).
@@ -1044,13 +1086,24 @@ class SolverBase:
         ``MultiGPU/Diffusion3d_Baseline/main.c:189``). A Mosaic/Pallas
         failure at dispatch under ``impl='pallas'`` retries one kernel-
         ladder rung down (:meth:`_with_ladder`)."""
-        def call():
-            with self._dispatch_span("run", iters=int(num_iters)):
-                return self._run_impl(state, num_iters)
+        with self._dispatch("run", steps=num_iters):
+            def call():
+                with self._dispatch_span("run", iters=int(num_iters)):
+                    return self._run_impl(state, num_iters)
 
-        return self._with_ladder(call)
+            return self._with_ladder(call)
 
     def _run_impl(self, state: SolverState, num_iters: int) -> SolverState:
+        with annotate(RESOLVE_SPAN):
+            f = self._run_program(num_iters)
+        return self._finish(
+            f, (state.u, state.t),
+            lambda u, t: SolverState(u=u, t=t, it=state.it + num_iters),
+        )
+
+    def _run_program(self, num_iters: int):
+        """The compiled program that marches ``num_iters`` steps on the
+        engaged rung."""
         fused = self._fused_stepper()
         if fused is not None:
             refresh, offsets_fn, exch = self._fused_sharded_ctx(fused)
@@ -1067,12 +1120,10 @@ class SolverBase:
                     kw["offsets"] = offsets_fn()
                 return fused.run(u, t, num_iters, **kw)
 
-            f = self._compiled(
+            return self._compiled(
                 ("fused_run", num_iters), lambda: self._wrap(block),
                 steps=int(num_iters),
             )
-            u, t = f(state.u, state.t)
-            return SolverState(u=u, t=t, it=state.it + num_iters)
 
         if self._precision_mode() == "bf16":
             # bf16-storage generic rung: the loop-resident state is the
@@ -1099,10 +1150,8 @@ class SolverBase:
                     0, num_iters, lambda i, c: self._local_step(*c), (u, t)
                 )
 
-        f = self._compiled(("run", num_iters), lambda: self._wrap(block),
-                           steps=int(num_iters))
-        u, t = f(state.u, state.t)
-        return SolverState(u=u, t=t, it=state.it + num_iters)
+        return self._compiled(("run", num_iters), lambda: self._wrap(block),
+                              steps=int(num_iters))
 
     def advance_to(self, state: SolverState, t_end: float) -> SolverState:
         """March until ``t_end`` with the last step trimmed to land exactly
@@ -1116,14 +1165,26 @@ class SolverBase:
         stepper's speed — the reference Burgers drivers' *only* execution
         mode is ``while (t < tEnd)`` over the tuned kernels
         (``MultiGPU/Burgers3d_Baseline/main.c:190-317``)."""
-        def call():
-            with self._dispatch_span("advance_to", mode="t_end",
-                                     t_end=float(t_end)):
-                return self._advance_impl(state, t_end)
+        with self._dispatch("advance_to"):
+            def call():
+                with self._dispatch_span("advance_to", mode="t_end",
+                                         t_end=float(t_end)):
+                    return self._advance_impl(state, t_end)
 
-        return self._with_ladder(call, mode="t_end")
+            return self._with_ladder(call, mode="t_end")
 
     def _advance_impl(self, state: SolverState, t_end: float) -> SolverState:
+        with annotate(RESOLVE_SPAN):
+            f = self._advance_program()
+            te = jnp.asarray(t_end, state.t.dtype)
+        return self._finish(
+            f, (state.u, state.t, te),
+            lambda u, t, steps: SolverState(u=u, t=t, it=state.it + steps),
+        )
+
+    def _advance_program(self):
+        """The compiled program that marches to a traced ``t_end`` on
+        the engaged rung."""
         fused = self._fused_stepper(mode="t_end")
         if fused is not None and hasattr(fused, "run_to"):
             refresh, offsets_fn, exch = self._fused_sharded_ctx(fused)
@@ -1133,11 +1194,8 @@ class SolverBase:
                 return fused.run_to(u, t, te, refresh=refresh, offsets=offs,
                                     exch=exch)
 
-            f = self._compiled("fused_adv", lambda: self._wrap(fblock, 2, 2))
-            u, t, steps = f(
-                state.u, state.t, jnp.asarray(t_end, state.t.dtype)
-            )
-            return SolverState(u=u, t=t, it=state.it + steps)
+            return self._compiled("fused_adv",
+                                  lambda: self._wrap(fblock, 2, 2))
 
         if self._precision_mode() == "bf16":
             # bf16-storage generic rung, t_end mode: same packed loop
@@ -1177,12 +1235,8 @@ class SolverBase:
                                       (u, t, jnp.zeros((), jnp.int32)))
 
         # check=False: no vma/replication rule exists for while_loop
-        f = self._compiled("adv", lambda: self._wrap(block, 2, 2,
-                                                     check=False))
-        u, t, steps = f(
-            state.u, state.t, jnp.asarray(t_end, state.t.dtype)
-        )
-        return SolverState(u=u, t=t, it=state.it + steps)
+        return self._compiled("adv", lambda: self._wrap(block, 2, 2,
+                                                        check=False))
 
     # ------------------------------------------------------------------ #
     # Ensemble (leading-member-axis) execution — one compiled
@@ -1444,80 +1498,88 @@ class SolverBase:
         HBM update, no second ``(B,*grid)`` buffer) and CONSUMES it:
         ``estate`` must not be touched after this returns — any reuse
         raises loudly on every backend (:func:`_consume_donated`)."""
+        with self._dispatch("run_ensemble", steps=num_iters):
+            with annotate(RESOLVE_SPAN):
+                B = estate.members
+                names, ops = self._ensemble_pack(operands, B)
+                self._ensemble_gate(names)
+                fused = self._ensemble_fused() if not names else None
+                mtok = self._ensemble_mesh_token()
+                slab_fold = (
+                    fused is not None
+                    and fused.engaged_label == "fused-whole-run-slab"
+                )
+                if slab_fold:
+                    label = "ensemble-fold[fused-whole-run-slab]"
+                elif fused is not None:
+                    label = f"ensemble-vmap[{fused.engaged_label}]"
+                else:
+                    label = "ensemble-vmap[generic-xla]"
+                self._ensemble_record(B, label, "iters", names)
+            with self._dispatch_span("run_ensemble", mode="t_end",
+                                     iters=int(num_iters), members=B):
+                with annotate(RESOLVE_SPAN):
+                    f, args = self._ensemble_run_program(
+                        estate, num_iters, names, ops, fused, slab_fold,
+                        mtok, donate,
+                    )
+
+                def done(u, t):
+                    if donate:
+                        _consume_donated(estate.u)
+                    return EnsembleState(u=u, t=t, it=estate.it + num_iters)
+
+                return self._finish(f, args, done)
+
+    def _ensemble_run_program(self, estate, num_iters, names, ops, fused,
+                              slab_fold, mtok, donate):
+        """``(program, operands)`` of :meth:`run_ensemble`'s dispatch."""
         B = estate.members
-        names, ops = self._ensemble_pack(operands, B)
-        self._ensemble_gate(names)
-        fused = self._ensemble_fused() if not names else None
-        mtok = self._ensemble_mesh_token()
-        slab_fold = (
-            fused is not None
-            and fused.engaged_label == "fused-whole-run-slab"
-        )
         if slab_fold:
-            label = "ensemble-fold[fused-whole-run-slab]"
-        elif fused is not None:
-            label = f"ensemble-vmap[{fused.engaged_label}]"
-        else:
-            label = "ensemble-vmap[generic-xla]"
-        self._ensemble_record(B, label, "iters", names)
-        with self._dispatch_span("run_ensemble", mode="t_end",
-                                 iters=int(num_iters), members=B):
-            if slab_fold:
-                # B folded into the slab rung's Pallas grid: ONE
-                # whole-run program per device advances its members
-                # (under a members-only mesh each device runs the fold
-                # over its own member shard)
-                def block(us, ts):
-                    return fused.run_batched(us, ts, num_iters)
-
-                f = self._compiled(
-                    ("ens_slab_run", num_iters, B, mtok),
-                    lambda: self._ensemble_wrap(block, 1, 1,
-                                                donate=donate),
-                    steps=int(num_iters), donate=donate,
-                )
-                u, t = f(estate.u, estate.t)
-                if donate:
-                    _consume_donated(estate.u)
-                return EnsembleState(u=u, t=t, it=estate.it + num_iters)
-
-            if fused is not None:
-                def block(us, ts):
-                    return jax.vmap(
-                        lambda u, t: fused.run(u, t, num_iters)
-                    )(us, ts)
-
-                f = self._compiled(
-                    ("ens_fused_run", num_iters, B, mtok),
-                    lambda: self._ensemble_wrap(block, 1, 1,
-                                                donate=donate),
-                    steps=int(num_iters), donate=donate,
-                )
-                u, t = f(estate.u, estate.t)
-                if donate:
-                    _consume_donated(estate.u)
-                return EnsembleState(u=u, t=t, it=estate.it + num_iters)
-
-            def member(u, t, p):
-                ov = {n: p[i] for i, n in enumerate(names)} or None
-
-                def body(i, c):
-                    return self._local_step(c[0], c[1], overrides=ov)
-
-                return lax.fori_loop(0, num_iters, body, (u, t))
-
-            def block(us, ts, ps):
-                return jax.vmap(member)(us, ts, ps)
+            # B folded into the slab rung's Pallas grid: ONE whole-run
+            # program per device advances its members (under a
+            # members-only mesh each device runs the fold over its own
+            # member shard)
+            def block(us, ts):
+                return fused.run_batched(us, ts, num_iters)
 
             f = self._compiled(
-                ("ens_run", num_iters, B, names, mtok),
-                lambda: self._ensemble_wrap(block, 2, 1, donate=donate),
+                ("ens_slab_run", num_iters, B, mtok),
+                lambda: self._ensemble_wrap(block, 1, 1, donate=donate),
                 steps=int(num_iters), donate=donate,
             )
-            u, t = f(estate.u, estate.t, ops)
-            if donate:
-                _consume_donated(estate.u)
-            return EnsembleState(u=u, t=t, it=estate.it + num_iters)
+            return f, (estate.u, estate.t)
+
+        if fused is not None:
+            def block(us, ts):
+                return jax.vmap(
+                    lambda u, t: fused.run(u, t, num_iters)
+                )(us, ts)
+
+            f = self._compiled(
+                ("ens_fused_run", num_iters, B, mtok),
+                lambda: self._ensemble_wrap(block, 1, 1, donate=donate),
+                steps=int(num_iters), donate=donate,
+            )
+            return f, (estate.u, estate.t)
+
+        def member(u, t, p):
+            ov = {n: p[i] for i, n in enumerate(names)} or None
+
+            def body(i, c):
+                return self._local_step(c[0], c[1], overrides=ov)
+
+            return lax.fori_loop(0, num_iters, body, (u, t))
+
+        def block(us, ts, ps):
+            return jax.vmap(member)(us, ts, ps)
+
+        f = self._compiled(
+            ("ens_run", num_iters, B, names, mtok),
+            lambda: self._ensemble_wrap(block, 2, 1, donate=donate),
+            steps=int(num_iters), donate=donate,
+        )
+        return f, (estate.u, estate.t, ops)
 
     def _ensemble_advance_block(self, names, max_steps,
                                 per_member_te: bool):
@@ -1607,52 +1669,51 @@ class SolverBase:
         ``RuntimeError`` on every backend."""
         import numpy as _np
 
-        B = estate.members
-        names, ops = self._ensemble_pack(operands, B)
-        self._ensemble_gate(names)
-        mtok = self._ensemble_mesh_token()
-        te_host = _np.asarray(t_end, dtype=_np.float64)
-        per_member_te = te_host.ndim > 0
-        if per_member_te and te_host.reshape(-1).shape[0] != B:
-            raise ValueError(
-                f"t_end has {te_host.reshape(-1).shape[0]} values for "
-                f"{B} members — pass a scalar or one horizon per member"
-            )
-        self._ensemble_record(B, "ensemble-vmap[generic-xla]", "t_end",
-                              names)
-        with self._dispatch_span("advance_to_ensemble", mode="t_end",
-                                 t_end=float(_np.max(te_host)),
-                                 members=B):
-            block = self._ensemble_advance_block(names, max_steps,
-                                                 per_member_te)
-            if per_member_te:
-                f = self._compiled(
-                    ("ens_adv", B, names, mtok, max_steps, "vte"),
-                    lambda: self._ensemble_wrap(block, 3, 2,
-                                                donate=donate),
-                    donate=donate,
-                )
-                u, t, steps = f(
-                    estate.u, estate.t, ops,
-                    jnp.asarray(te_host.reshape(-1), estate.t.dtype),
-                )
-                if donate:
-                    _consume_donated(estate.u)
-                return EnsembleState(u=u, t=t, it=estate.it + steps)
+        with self._dispatch("advance_to_ensemble"):
+            with annotate(RESOLVE_SPAN):
+                B = estate.members
+                names, ops = self._ensemble_pack(operands, B)
+                self._ensemble_gate(names)
+                mtok = self._ensemble_mesh_token()
+                te_host = _np.asarray(t_end, dtype=_np.float64)
+                per_member_te = te_host.ndim > 0
+                if per_member_te and te_host.reshape(-1).shape[0] != B:
+                    raise ValueError(
+                        f"t_end has {te_host.reshape(-1).shape[0]} values "
+                        f"for {B} members — pass a scalar or one horizon "
+                        f"per member"
+                    )
+                self._ensemble_record(B, "ensemble-vmap[generic-xla]",
+                                      "t_end", names)
+            with self._dispatch_span("advance_to_ensemble", mode="t_end",
+                                     t_end=float(_np.max(te_host)),
+                                     members=B):
+                with annotate(RESOLVE_SPAN):
+                    block = self._ensemble_advance_block(names, max_steps,
+                                                         per_member_te)
+                    if per_member_te:
+                        f = self._compiled(
+                            ("ens_adv", B, names, mtok, max_steps, "vte"),
+                            lambda: self._ensemble_wrap(block, 3, 2,
+                                                        donate=donate),
+                            donate=donate,
+                        )
+                        te = jnp.asarray(te_host.reshape(-1), estate.t.dtype)
+                    else:
+                        f = self._compiled(
+                            ("ens_adv", B, names, mtok, max_steps),
+                            lambda: self._ensemble_wrap(
+                                block, 2, 2, n_in_global=1, donate=donate),
+                            donate=donate,
+                        )
+                        te = jnp.asarray(t_end, estate.t.dtype)
 
-            f = self._compiled(
-                ("ens_adv", B, names, mtok, max_steps),
-                lambda: self._ensemble_wrap(block, 2, 2, n_in_global=1,
-                                            donate=donate),
-                donate=donate,
-            )
-            u, t, steps = f(
-                estate.u, estate.t, ops,
-                jnp.asarray(t_end, estate.t.dtype),
-            )
-            if donate:
-                _consume_donated(estate.u)
-            return EnsembleState(u=u, t=t, it=estate.it + steps)
+                def done(u, t, steps):
+                    if donate:
+                        _consume_donated(estate.u)
+                    return EnsembleState(u=u, t=t, it=estate.it + steps)
+
+                return self._finish(f, (estate.u, estate.t, ops, te), done)
 
     def prewarm_advance_to_ensemble(self, members: int,
                                     operand_names=(),

@@ -298,14 +298,22 @@ class _IntrospectedDispatch:
             return self._fn(*args)
         sig = _signature(args)
         if self._compiled is None:
-            compiled, compile_s, aot, saved = self._aot_resolve(args)
+            from multigpu_advectiondiffusion_tpu.utils.profiling import (
+                COMPILE_SPAN,
+                INTROSPECT_SPAN,
+                annotate,
+            )
+
+            with annotate(COMPILE_SPAN, key=self._key):
+                compiled, compile_s, aot, saved = self._aot_resolve(args)
             self._compiled = compiled
             self._signature = sig
-            self.record = _capture(
-                compiled, self._solver, self._key, self._steps, compile_s,
-                aot=aot, compile_seconds_saved=saved,
-                donated=self._donated,
-            )
+            with annotate(INTROSPECT_SPAN, key=self._key):
+                self.record = _capture(
+                    compiled, self._solver, self._key, self._steps,
+                    compile_s, aot=aot, compile_seconds_saved=saved,
+                    donated=self._donated,
+                )
         elif self._signature is None:
             self._signature = sig  # prewarmed from shapes: first call
         elif sig != self._signature:

@@ -2,26 +2,38 @@
 
 TPU replacement for the reference's profiling layer (SURVEY §5): per-rank
 ``nvprof`` wrapping (``Diffusion3d_Baseline/profile.sh:2``) becomes
-``jax.profiler`` traces viewable in TensorBoard/Perfetto, and the
-MPI_Wtime double-barrier walltime sandwich (``main.c:139-147,184-187``)
-becomes :class:`Stopwatch` segments around ``block_until_ready``.
+``jax.profiler`` traces viewable in TensorBoard/Perfetto. The program's
+host spans (``TraceAnnotation`` events) land in the same capture as the
+device ops, on the host's clock; a v5e's device planes read up to ~2 ms
+early against it, so compare host times with host times:
+
+* ``tpucfd.dispatch`` — one per public driver call (``run``, ``step``,
+  ``advance_to``, ``run_ensemble``, ``advance_to_ensemble``), opened
+  first and closed on return, with the stats ``op``, ``steps`` (where
+  the call fixes them), ``programs`` and ``cache`` (:class:`dispatch`);
+  its children ``tpucfd.dispatch.resolve`` (rung, stepper, halo context
+  and dispatch-cache lookup), ``tpucfd.<op>[<stepper>]`` (the call's
+  body under the engaged rung), ``tpucfd.dispatch.execute`` (the
+  compiled program's call, which returns before the device runs it) and
+  ``tpucfd.dispatch.state`` (the returned state, its ``it`` update);
+* ``tpucfd.compile`` (stat ``key``) around a program's first-call
+  lower+compile or AOT load, and ``tpucfd.introspect`` around the
+  capture of its cost and memory analyses (``telemetry/xprof.py``).
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-import time
-from typing import Dict, Optional
 
 import jax
 
-
-def _fetch_sync(x):
-    if hasattr(x, "dtype"):
-        from multigpu_advectiondiffusion_tpu.bench.timing import sync
-
-        sync(x)
+DISPATCH_SPAN = "tpucfd.dispatch"
+RESOLVE_SPAN = "tpucfd.dispatch.resolve"
+EXECUTE_SPAN = "tpucfd.dispatch.execute"
+STATE_SPAN = "tpucfd.dispatch.state"
+COMPILE_SPAN = "tpucfd.compile"
+INTROSPECT_SPAN = "tpucfd.introspect"
 
 
 # jax.profiler supports exactly one trace per process; this flag makes
@@ -70,37 +82,6 @@ def trace(log_dir: str):
             pass  # teardown must never mask the body's exception
 
 
-class Stopwatch:
-    """Named walltime segments (HtD/compute/DtH in the reference's
-    summary become e.g. init/compile/solve/io here)."""
-
-    def __init__(self):
-        self.segments: Dict[str, float] = {}
-
-    @contextlib.contextmanager
-    def segment(self, name: str, sync: Optional[object] = None):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            if sync is not None:
-                # Host-fetch sync, the benchmark's discipline (see
-                # bench/timing.py).
-                jax.tree.map(_fetch_sync, sync)
-            self.segments[name] = (
-                self.segments.get(name, 0.0) + time.perf_counter() - t0
-            )
-
-    def report(self) -> str:
-        total = sum(self.segments.values())
-        lines = [f"{'segment':<16} {'seconds':>10} {'share':>7}"]
-        for name, s in self.segments.items():
-            share = 100.0 * s / total if total else 0.0
-            lines.append(f"{name:<16} {s:>10.4f} {share:>6.1f}%")
-        lines.append(f"{'total':<16} {total:>10.4f}")
-        return "\n".join(lines)
-
-
 class annotate:
     """Named ``jax.profiler.TraceAnnotation`` span, usable two ways:
 
@@ -110,25 +91,60 @@ class annotate:
     * context manager — ``with annotate("halo-exchange"): ...`` labels
       an ad-hoc host-side region (e.g. one supervised chunk) in the
       captured trace.
+
+    Keyword ``stats`` become stats of the span's trace event; pass only
+    values the host already holds.
     """
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **stats):
         self.name = name
+        self.stats = stats
         self._span = None
 
     def __call__(self, fn):
         @functools.wraps(fn)
         def inner(*a, **k):
-            with jax.profiler.TraceAnnotation(self.name):
+            with jax.profiler.TraceAnnotation(self.name, **self.stats):
                 return fn(*a, **k)
 
         return inner
 
     def __enter__(self):
-        self._span = jax.profiler.TraceAnnotation(self.name)
+        self._span = jax.profiler.TraceAnnotation(self.name, **self.stats)
         self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
         span, self._span = self._span, None
         return span.__exit__(*exc)
+
+
+class dispatch(annotate):
+    """The ``tpucfd.dispatch`` span of one public driver call.
+
+    Stats ``op`` and ``steps`` (omitted where the trip count is data-
+    dependent) are set on entry; on exit ``programs`` (compiled programs
+    the call invoked through the dispatch cache, :meth:`program`) and,
+    when there was one, ``cache`` (``miss`` if the cache built a program
+    for this call, else ``hit``)."""
+
+    def __init__(self, op: str, steps=None):
+        stats = {"op": op}
+        if steps is not None:
+            stats["steps"] = int(steps)
+        super().__init__(DISPATCH_SPAN, **stats)
+        self.programs = 0
+        self.built = False
+
+    def program(self, built: bool) -> None:
+        """One program invoked through the dispatch cache; ``built``
+        when the cache had to build it."""
+        self.programs += 1
+        self.built = self.built or built
+
+    def __exit__(self, *exc):
+        counts = {"programs": self.programs}
+        if self.programs:
+            counts["cache"] = "miss" if self.built else "hit"
+        self._span.set_metadata(**counts)
+        return super().__exit__(*exc)

@@ -2,7 +2,8 @@
 
 Covers the observability layer end to end: the JSONL event sink (span
 nesting, counter accumulation, well-formedness), the profiling helpers
-(``Stopwatch``, the fixed ``annotate``), the static cost model against
+(the fixed ``annotate``, the dispatch layer's ``tpucfd.dispatch`` spans
+in a captured trace), the static cost model against
 hand-computed bytes/FLOPs for one diffusion and one WENO5 rung, the
 supervised CLI run's ``--metrics`` stream (span + counter + physics
 events, schema'd summary with mass-drift and roofline fields), the
@@ -15,7 +16,7 @@ from __future__ import annotations
 import json
 import math
 import os
-import time
+import re
 
 import numpy as np
 import pytest
@@ -32,17 +33,18 @@ from multigpu_advectiondiffusion_tpu import (
     telemetry,
 )
 from multigpu_advectiondiffusion_tpu.cli.__main__ import main as cli_main
+from multigpu_advectiondiffusion_tpu.models.ensemble import EnsembleSolver
 from multigpu_advectiondiffusion_tpu.parallel.mesh import Decomposition
 from multigpu_advectiondiffusion_tpu.resilience import faults, supervise_run
 from multigpu_advectiondiffusion_tpu.telemetry import costmodel
-from multigpu_advectiondiffusion_tpu.utils.profiling import (
-    Stopwatch,
-    annotate,
-)
+from multigpu_advectiondiffusion_tpu.utils import profiling
+from multigpu_advectiondiffusion_tpu.utils.profiling import annotate
 from multigpu_advectiondiffusion_tpu.utils.summary import (
     SUMMARY_SCHEMA,
     RunSummary,
 )
+
+from host_trace import host_events
 
 
 def _events(path) -> list:
@@ -63,29 +65,8 @@ def _diffusion2d(**kw):
 
 
 # --------------------------------------------------------------------- #
-# Profiling helpers (satellite: annotate fix, Stopwatch coverage)
+# Profiling helpers (annotate, the dispatch layer's spans)
 # --------------------------------------------------------------------- #
-def test_stopwatch_accumulates_named_segments():
-    sw = Stopwatch()
-    with sw.segment("solve"):
-        time.sleep(0.01)
-    with sw.segment("solve"):  # same name accumulates
-        time.sleep(0.01)
-    with sw.segment("io"):
-        pass
-    assert set(sw.segments) == {"solve", "io"}
-    assert sw.segments["solve"] >= 0.02
-    rep = sw.report()
-    assert "solve" in rep and "io" in rep and "total" in rep
-
-
-def test_stopwatch_segment_syncs_operand():
-    sw = Stopwatch()
-    with sw.segment("compute", sync=jnp.ones((8, 8))):
-        pass
-    assert sw.segments["compute"] > 0.0
-
-
 def test_annotate_preserves_wrapped_metadata():
     @annotate("labeled-span")
     def solve_step(x):
@@ -101,6 +82,132 @@ def test_annotate_usable_as_context_manager():
     with annotate("ad-hoc-region"):
         x = jnp.sum(jnp.ones((4, 4)))
     assert float(x) == 16.0
+
+
+def _tops(events) -> list:
+    return [e for e in events if e.name == profiling.DISPATCH_SPAN]
+
+
+def _diffusion3d_small(**kw):
+    cfg = DiffusionConfig(grid=Grid.make(12, 10, 8, lengths=3.0),
+                          dtype="float32", **kw)
+    return DiffusionSolver(cfg)
+
+
+def test_dispatch_span_holds_every_host_call_of_run(tmp_path):
+    """``SolverBase.run`` opens ``tpucfd.dispatch`` before anything
+    else: every Python call ``run`` makes, bar the span's own machinery,
+    lies inside it (the Python tracer records them on the same clock),
+    and so do the host events of both device programs a chunk starts
+    (the compiled block and the ``it`` update). The counts ride the
+    span as stats: the first call builds its program, the second finds
+    it in the dispatch cache."""
+    solver = _diffusion3d_small(impl="xla")
+
+    def two_chunks():
+        state = solver.run(solver.initial_state(), 3)
+        solver.run(state, 3).u.block_until_ready()
+
+    events = host_events(tmp_path, two_chunks, python_tracer=True)
+    tops = _tops(events)
+    assert [t.stats for t in tops] == [
+        {"op": "run", "steps": 3, "programs": 1, "cache": "miss"},
+        {"op": "run", "steps": 3, "programs": 1, "cache": "hit"},
+    ]
+    calls = [e for e in events if re.fullmatch(r"\$base\.py:\d+ run", e.name)]
+    assert len(calls) == 2
+    machinery = re.compile(r"\$(profiling\.py|contextlib\.py|builtins)"
+                           r"|\$base\.py:\d+ _dispatch$")
+    for call, top in zip(calls, tops):
+        assert call.holds(top)
+        inside = [e for e in events if call.holds(e) and e is not call]
+        made = [e for e in inside
+                if e.name.startswith("$") and not machinery.match(e.name)]
+        names = {e.name.split()[-1] for e in made}
+        assert {"_with_ladder", "engaged_path", "_run_program", "_compiled",
+                "_finish"} <= names
+        outside = [e.name for e in made if not top.holds(e)]
+        assert outside == []
+        children = [e for e in inside
+                    if e.name.startswith("tpucfd.") and e is not top]
+        assert {profiling.RESOLVE_SPAN, profiling.EXECUTE_SPAN,
+                profiling.STATE_SPAN, "tpucfd.run[generic-xla]"} <= {
+                    e.name for e in children}
+        assert all(top.holds(e) for e in children)
+        assert top.start < min(e.start for e in children
+                               if e.name == profiling.RESOLVE_SPAN)
+        programs = {e.name for e in inside
+                    if e.name.startswith("PjitFunction(")}
+        assert any("block" in n for n in programs)
+        assert any("add" in n for n in programs)
+        assert all(top.holds(e) for e in inside
+                   if e.name.startswith("PjitFunction("))
+
+
+def test_dispatch_span_stats_read_no_device_value(tmp_path, monkeypatch):
+    """No stat of the dispatch spans (nor anything else ``run`` does)
+    reads a device value on the host: with every host read of a
+    ``jax.Array`` made to fail, a cached chunk still dispatches and its
+    span carries its stats."""
+    from jax._src.array import ArrayImpl
+
+    solver = _diffusion3d_small(impl="xla")
+    state = solver.run(solver.initial_state(), 2)
+    state.u.block_until_ready()
+
+    def host_read(self):
+        raise AssertionError("a device value was read on the host")
+
+    out = []
+    monkeypatch.setattr(ArrayImpl, "_value", property(host_read))
+    events = host_events(tmp_path, lambda: out.append(solver.run(state, 2)))
+    monkeypatch.undo()
+    assert [t.stats for t in _tops(events)] == [
+        {"op": "run", "steps": 2, "programs": 1, "cache": "hit"}]
+    assert int(out[0].it) == int(state.it) + 2
+
+
+def _ensemble(members=2):
+    g = Grid.make(12, 10, 8, lengths=(1.2, 1.0, 0.8))
+    cfg = DiffusionConfig(grid=g, dtype="float32", impl="xla",
+                          ic="gaussian")
+    return EnsembleSolver(DiffusionSolver, cfg, members)
+
+
+# public driver -> (its ``steps`` stat, one call of it)
+DRIVERS = {
+    "run": (3, lambda s: s.run(s.initial_state(), 3)),
+    "step": (1, lambda s: s.step(s.initial_state())),
+    "advance_to": (None, lambda s: s.advance_to(s.initial_state(), 0.01)),
+    "run_ensemble": (2, lambda e: e.run(e.initial_state(), 2)),
+    "advance_to_ensemble": (
+        None, lambda e: e.advance_to(e.initial_state(), 0.001)),
+}
+
+
+@pytest.mark.parametrize("op", sorted(DRIVERS))
+def test_every_public_driver_opens_one_dispatch_span(tmp_path, op):
+    """Each public driver is one top-level ``tpucfd.dispatch`` span that
+    holds its resolve, execute and state children and the rung's
+    ``tpucfd.<op>[<stepper>]`` span; ``steps`` is a stat only where the
+    call fixes the trip count."""
+    steps, drive = DRIVERS[op]
+    target = (_ensemble() if op.endswith("_ensemble")
+              else _diffusion3d_small(impl="xla"))
+    out = []
+    events = host_events(tmp_path, lambda: out.append(drive(target)))
+    jax.block_until_ready(out)
+    (top,) = _tops(events)
+    want = {"op": op, "programs": 1, "cache": "miss"}
+    if steps is not None:
+        want["steps"] = steps
+    assert top.stats == want
+    children = {e.name for e in events
+                if e.name.startswith("tpucfd.") and e is not top
+                and top.holds(e)}
+    assert {profiling.RESOLVE_SPAN, profiling.EXECUTE_SPAN,
+            profiling.STATE_SPAN} <= children
+    assert any(n.startswith(f"tpucfd.{op}[") for n in children)
 
 
 # --------------------------------------------------------------------- #

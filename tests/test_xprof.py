@@ -36,6 +36,9 @@ from multigpu_advectiondiffusion_tpu.telemetry import (
     schema,
     xprof,
 )
+from multigpu_advectiondiffusion_tpu.utils import profiling
+
+from host_trace import host_events
 
 
 def _events(path) -> list:
@@ -124,6 +127,31 @@ def test_dispatch_capture_reuses_one_compile_per_program():
     assert len(xprof.records(solver)) == 1
     entry = solver._cache[("run", 2)]
     assert entry._compiled is not None and not entry._fallback
+
+
+def test_compile_and_introspection_spans_on_the_first_call_only(tmp_path):
+    """A program's first call compiles inside ``tpucfd.compile`` (stat
+    ``key``: the dispatch-cache key) and captures its analyses inside
+    ``tpucfd.introspect``, both within that call's execute span; the
+    next call opens neither."""
+    solver = _diffusion3d(impl="xla")
+
+    def two_calls():
+        st = solver.run(solver.initial_state(), 2)
+        solver.run(st, 2).u.block_until_ready()
+
+    events = host_events(tmp_path, two_calls)
+    executes = [e for e in events if e.name == profiling.EXECUTE_SPAN]
+    assert len(executes) == 2
+    for name in (profiling.COMPILE_SPAN, profiling.INTROSPECT_SPAN):
+        (span,) = [e for e in events if e.name == name]
+        assert span.stats == {"key": "('run', 2)"}
+        assert executes[0].holds(span)
+    compile_span = next(e for e in events
+                        if e.name == profiling.COMPILE_SPAN)
+    introspect = next(e for e in events
+                      if e.name == profiling.INTROSPECT_SPAN)
+    assert compile_span.end <= introspect.start
 
 
 def test_dispatch_retraces_only_on_argument_drift():
