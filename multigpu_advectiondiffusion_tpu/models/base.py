@@ -649,13 +649,19 @@ class SolverBase:
         captures show ``tpucfd.run[fused-whole-run-slab]``-style spans
         over the whole rung hierarchy) plus, when a telemetry sink is
         installed, a structured ``solver.<op>`` span carrying the
-        engaged stepper/impl/overlap."""
+        engaged stepper/impl/overlap. The profiler span takes the
+        stepper's ``hbm_bytes_per_step`` as a stat where it declares
+        one (:meth:`engaged_path`)."""
         from multigpu_advectiondiffusion_tpu import telemetry
 
         with annotate(RESOLVE_SPAN):
             eng = self.engaged_path(mode=mode)
+        plan = eng.get("hbm_bytes_per_step")
+        stats = {} if plan is None else {"hbm_bytes_per_step": plan}
         stack = contextlib.ExitStack()
-        stack.enter_context(annotate(f"tpucfd.{op}[{eng['stepper']}]"))
+        stack.enter_context(
+            annotate(f"tpucfd.{op}[{eng['stepper']}]", **stats)
+        )
         sink = telemetry.get_sink()
         if sink.active:
             stack.enter_context(
@@ -853,7 +859,9 @@ class SolverBase:
         ``fallback`` (reason the fused stepper was declined, or None),
         and — when the kernel ladder degraded after a Mosaic/Pallas
         dispatch failure — ``degraded``, the downgrade event list
-        (from/to rung + failure text); absent on healthy runs.
+        (from/to rung + failure text); absent on healthy runs. A fused
+        stepper that declares its plan adds ``hbm_bytes_per_step``: the
+        bytes its kernels DMA from and to HBM per step on one shard.
 
         ``mode`` mirrors the execution dispatch: ``"t_end"`` engages the
         fused stepper only when it has ``run_to`` (``advance_to``'s extra
@@ -909,6 +917,9 @@ class SolverBase:
                 "precision": self._precision_mode(),
                 "fallback": None,
             }
+            plan = getattr(fused, "hbm_bytes_per_step", None)
+            if plan is not None:
+                out["hbm_bytes_per_step"] = plan
             if self._tuned is not None:
                 out["tuned"] = self._tuned_summary()
             if self._degrade_events:

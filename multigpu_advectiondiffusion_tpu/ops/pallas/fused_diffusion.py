@@ -14,13 +14,14 @@ traffic (read stage input + read step input + write output, ~12 B/cell):
   rewritten — with ``reference_parity`` Dirichlet walls the RHS is zeroed
   on the 2-cell boundary band (``Laplace3d.m:21``), so boundary cells and
   ghosts are constant through every stage.
-* Each stage kernel DMAs a z-slab (+2 halo rows), evaluates the 13-point
-  Laplacian with in-slab value slices (z) and circular shifts (y/x —
-  wraparound touches only masked ghost columns), applies the RK stage
-  combination ``a*u + b*(v + dt*L(v))``, re-imposes the Dirichlet faces
-  (``heat3d.m:65-67``), and writes only the core z-rows back — the
-  output buffer is aliased to a dead input buffer whose ghost cells are
-  already valid.
+* Each stage kernel marches z-blocks through a VMEM ring of z-planes
+  that fetches every plane of its input from HBM once per call,
+  evaluates the 13-point Laplacian plane by plane with ring planes (z)
+  and circular shifts (y/x — wraparound touches only masked ghost
+  columns), applies the RK stage combination ``a*u + b*(v + dt*L(v))``,
+  re-imposes the Dirichlet faces (``heat3d.m:65-67``), and writes only
+  the core z-rows back — the output buffer is aliased to a dead input
+  buffer whose ghost cells are already valid.
 * Buffer choreography per step (three live padded buffers, zero allocs):
   ``T1 = stage1(S)``, ``T2 = stage2(T1, S)``, ``S' = stage3(T2, S) → S``.
   Stage 3 writes in place over ``S`` while reading it: each grid block
@@ -82,13 +83,31 @@ def _shift(x, off: int, axis: int):
     return pltpu.roll(x, (-off) % n, axis)
 
 
+def _ring_geometry(bz: int, n_grid: int):
+    """``(depth, shift)`` of a stage call's VMEM ring of ``v`` z-planes.
+
+    Call-relative plane ``p`` lives at ring index ``(p + shift) % depth``.
+    Block ``k`` reads planes ``[k*bz, k*bz + bz + 2R)`` while block
+    ``k+1``'s ``bz`` new planes land, so the ring holds ``2*bz + 2R``
+    planes at once. ``depth`` rounds that up to a block multiple and
+    ``shift`` aligns each block's new planes on a block boundary, so
+    every fetch is one contiguous DMA (block 0's whole slab included:
+    it sits at ``[shift, shift + bz + 2R)``). A one-block call keeps
+    just its slab.
+    """
+    if n_grid == 1:
+        return bz + 2 * R, 0
+    lead = -(-2 * R // bz) * bz  # 2R rounded up to a block multiple
+    return 2 * bz + lead, lead - 2 * R
+
+
 def _stage_kernel(
     dt_ref,
     v_hbm,
     u_hbm,
     g_hbm,
     out_hbm,
-    vs,
+    ring,
     us,
     res,
     sem_v,
@@ -110,15 +129,21 @@ def _stage_kernel(
     ghost_src: str | None = None,
     compute_dtype=None,
 ):
-    """One z-block of one RK stage, 2-slot double-buffered.
+    """One z-block of one RK stage; ``v`` streams through a VMEM ring.
 
-    The TPU grid is a sequential loop, so block ``k`` prefetches block
-    ``k+1``'s slab (and ``u`` rows) while it computes, and defers the
-    wait on its output DMA until the same slot is reused at ``k+2`` —
-    reads, compute, and writes of consecutive blocks overlap. All row
-    ranges of distinct blocks are disjoint, so the in-flight writes
-    never alias the prefetched reads (the in-place final stage reads its
-    ``u`` rows strictly before the overwriting DMA of the same block).
+    The TPU grid is a sequential loop. Block 0 fetches its whole
+    ``(bz + 2R)``-plane slab of the stage input ``v``; every later block
+    fetches only the ``bz`` planes past the 2R it shares with the block
+    before, which are still in the ring (:func:`_ring_geometry`). So each
+    plane of ``v`` leaves HBM once per call, as the reference's
+    register-pipelined ``LaplaceO4_async`` marches its window along z
+    (``MultiGPU/Diffusion3d_Baseline/Kernels.cu:207-261``). Block ``k``
+    prefetches block ``k+1``'s new planes (and ``u`` rows) while it
+    computes, and defers the wait on its output DMA until the same slot
+    is reused at ``k+2``. Output row ranges of distinct blocks are
+    disjoint, so the in-flight writes never alias the prefetched reads
+    (the in-place final stage reads its ``u`` rows strictly before the
+    overwriting DMA of the same block).
 
     ``dt`` is a runtime SMEM scalar, so the same compiled stages serve
     fixed-count runs AND the trimmed last step of ``run_to``. Roles for
@@ -132,40 +157,56 @@ def _stage_kernel(
     nz, ny, nx = global_shape
     if n_blocks_grid is None:
         n_blocks_grid = n_blocks
+    depth, shift = _ring_geometry(bz, n_blocks_grid)
     k = pl.program_id(0)  # this call's linear block index
     kz = k + kz_base  # absolute z-block index
+    z_start = kz_base * bz  # padded-buffer plane of call-relative plane 0
     slot = lax.rem(k, jnp.asarray(2, k.dtype))
     nslot = lax.rem(k + 1, jnp.asarray(2, k.dtype))
 
-    def copy_v(j, s):
-        z0 = (j + kz_base) * bz
+    def wrap(q):  # ring index of the plane at shifted position q
+        return lax.rem(q, jnp.asarray(depth, q.dtype))
+
+    def copy_slab():
+        # block 0: its whole slab, ghost rows from g_hbm in the edge roles
         if ghost_src is None:
             return [
                 pltpu.make_async_copy(
-                    v_hbm.at[pl.ds(z0, bz + 2 * R)], vs.at[s], sem_v.at[s]
+                    v_hbm.at[pl.ds(z_start, bz + 2 * R)],
+                    ring.at[pl.ds(shift, bz + 2 * R)],
+                    sem_v.at[0],
                 )
             ]
         if ghost_src == "lo":
             return [
                 pltpu.make_async_copy(
-                    g_hbm, vs.at[s, pl.ds(0, R)], sem_gv.at[s]
+                    g_hbm, ring.at[pl.ds(shift, R)], sem_gv.at[0]
                 ),
                 pltpu.make_async_copy(
-                    v_hbm.at[pl.ds(z0 + R, bz + R)],
-                    vs.at[s, pl.ds(R, bz + R)],
-                    sem_v.at[s],
+                    v_hbm.at[pl.ds(z_start + R, bz + R)],
+                    ring.at[pl.ds(shift + R, bz + R)],
+                    sem_v.at[0],
                 ),
             ]
         return [
             pltpu.make_async_copy(
-                v_hbm.at[pl.ds(z0, bz + R)],
-                vs.at[s, pl.ds(0, bz + R)],
-                sem_v.at[s],
+                v_hbm.at[pl.ds(z_start, bz + R)],
+                ring.at[pl.ds(shift, bz + R)],
+                sem_v.at[0],
             ),
             pltpu.make_async_copy(
-                g_hbm, vs.at[s, pl.ds(bz + R, R)], sem_gv.at[s]
+                g_hbm, ring.at[pl.ds(shift + bz + R, R)], sem_gv.at[0]
             ),
         ]
+
+    def copy_new(j, s):
+        # block j >= 1: the bz planes past the 2R it shares with block j-1
+        p = j * bz + 2 * R
+        return pltpu.make_async_copy(
+            v_hbm.at[pl.ds(z_start + p, bz)],
+            ring.at[pl.ds(wrap(p + shift), bz)],
+            sem_v.at[s],
+        )
 
     def copy_u(j, s):
         # u rows come from u_hbm — which for the in-place final stage is
@@ -185,88 +226,108 @@ def _stage_kernel(
 
     @pl.when(k == 0)
     def _():
-        for cp in copy_v(0, 0):
+        for cp in copy_slab():
             cp.start()
         if us is not None:
             copy_u(0, 0).start()
 
-    @pl.when(k + 1 < n_blocks_grid)
-    def _():
-        for cp in copy_v(k + 1, nslot):
-            cp.start()
-        if us is not None:
-            copy_u(k + 1, nslot).start()
+    if n_blocks_grid > 1:
+
+        @pl.when(k + 1 < n_blocks_grid)
+        def _():
+            copy_new(k + 1, nslot).start()
+            if us is not None:
+                copy_u(k + 1, nslot).start()
 
     if us is not None:
         copy_u(k, slot).wait()
-    for cp in copy_v(k, slot):
-        cp.wait()
+
+    @pl.when(k == 0)
+    def _():
+        for cp in copy_slab():
+            cp.wait()
+
+    if n_blocks_grid > 1:
+
+        @pl.when(k > 0)
+        def _():
+            copy_new(k, slot).wait()
+
+    # the res slot is recycled every other block: drain its previous
+    # write before this block fills it
+    @pl.when(k >= 2)
+    def _():
+        copy_w(k - 2, slot).wait()
 
     # bf16-storage rung: the state lives (and moves through HBM) at half
     # the bytes; arithmetic runs in ``compute_dtype`` (f32) so the RK
     # accumulation doesn't lose the stencil's cancellation digits
-    stored = vs[slot]
-    v = (
-        stored
-        if compute_dtype is None
-        else stored.astype(jnp.dtype(compute_dtype))
-    )
-    vc = v[R : R + bz]  # stage input, core z-rows, full y/x width
-    dtype = v.dtype
+    stored_dtype = ring.dtype
+    dtype = jnp.dtype(compute_dtype or stored_dtype)
     dt = dt_ref[0].astype(dtype)
+    first = wrap(k * bz + shift)  # ring index of the slab's plane 0
 
-    # 13-point O4 Laplacian (z-term via slab rows, y/x via masked
-    # circular shifts). Diffusivity is folded into each term's
-    # coefficient, so the rounding differs from the XLA path's
-    # per-axis-then-scale association by ~1 ulp per term.
-    acc = None
-    for axis in range(3):
-        for j, c in enumerate(O4_COEFFS):
-            coef = jnp.asarray(c * scales[axis], dtype)
-            term = (v[j : j + bz] if axis == 0 else _shift(vc, j - R, axis)) * coef
-            acc = term if acc is None else acc + term
-
-    u_in = None if us is None else us[slot].astype(dtype)
-    rk = (
-        b * (vc + dt * acc)
-        if a == 0.0
-        else a * u_in + b * (vc + dt * acc)
-    )
-
-    # Global interior-cell indices of this block (ghost offset already
-    # removed for z: the written rows are exactly the core rows). When
-    # sharded, ``offs_ref`` holds this shard's global offsets so the
+    # When sharded, ``offs_ref`` holds this shard's global offsets so the
     # band/face tests keep using *global* coordinates (reference-parity
     # walls are global, Laplace3d.m:21 / heat3d.m:65-67).
-    shp = vc.shape
     oz, oy, ox = (
         (offs_ref[0], offs_ref[1], offs_ref[2])
         if offs_ref is not None
         else (0, 0, 0)
     )
-    gz = lax.broadcasted_iota(jnp.int32, shp, 0) + kz * bz + oz
-    gy = lax.broadcasted_iota(jnp.int32, shp, 1) - R + oy
-    gx = lax.broadcasted_iota(jnp.int32, shp, 2) - R + ox
 
     def between(g, n):
         return (g >= band) & (g < n - band)
 
-    interior = between(gz, nz) & between(gy, ny) & between(gx, nx)
-    face = (
-        (gz == 0) | (gz == nz - 1)
-        | (gy == 0) | (gy == ny - 1)
-        | (gx == 0) | (gx == nx - 1)
-    )
-    frozen = jnp.where(face, jnp.asarray(bc_value, dtype), vc)
+    def plane(i, carry):
+        # output plane i of the block: slab planes i .. i + 2R
+        def slab_plane(j):
+            return ring[pl.ds(wrap(first + i + j), 1)].astype(dtype)
 
-    # the res slot is recycled every other block: drain its previous
-    # write before overwriting, then issue this block's write and leave
-    # it in flight (drained at k+2, or below on the last blocks)
-    @pl.when(k >= 2)
-    def _():
-        copy_w(k - 2, slot).wait()
+        rows = [slab_plane(j) for j in range(2 * R + 1)]
+        vc = rows[R]  # stage input, core z-row, full y/x width
 
-    res[slot] = jnp.where(interior, rk, frozen).astype(stored.dtype)
+        # 13-point O4 Laplacian (z-term via ring planes, y/x via masked
+        # circular shifts). Diffusivity is folded into each term's
+        # coefficient, so the rounding differs from the XLA path's
+        # per-axis-then-scale association by ~1 ulp per term.
+        acc = None
+        for axis in range(3):
+            for j, c in enumerate(O4_COEFFS):
+                coef = jnp.asarray(c * scales[axis], dtype)
+                tap = rows[j] if axis == 0 else _shift(vc, j - R, axis)
+                term = tap * coef
+                acc = term if acc is None else acc + term
+
+        rk = (
+            b * (vc + dt * acc)
+            if a == 0.0
+            else a * us[slot, pl.ds(i, 1)].astype(dtype) + b * (vc + dt * acc)
+        )
+
+        # Global interior-cell indices of this plane (ghost offset
+        # already removed for z: the written rows are exactly the core
+        # rows).
+        shp = vc.shape
+        gz = lax.broadcasted_iota(jnp.int32, shp, 0) + kz * bz + i + oz
+        gy = lax.broadcasted_iota(jnp.int32, shp, 1) - R + oy
+        gx = lax.broadcasted_iota(jnp.int32, shp, 2) - R + ox
+        interior = between(gz, nz) & between(gy, ny) & between(gx, nx)
+        face = (
+            (gz == 0) | (gz == nz - 1)
+            | (gy == 0) | (gy == ny - 1)
+            | (gx == 0) | (gx == nx - 1)
+        )
+        frozen = jnp.where(face, jnp.asarray(bc_value, dtype), vc)
+        res[slot, pl.ds(i, 1)] = jnp.where(interior, rk, frozen).astype(
+            stored_dtype
+        )
+        return carry
+
+    lax.fori_loop(0, bz, plane, 0)
+
+    # issue this block's write and leave it in flight (drained at k+2,
+    # or below on the last blocks)
     copy_w(k, slot).start()
 
     @pl.when(k == n_blocks_grid - 1)
@@ -280,6 +341,10 @@ def _make_stage(padded_shape, interior_shape, dtype, *, bz, scales, a, b,
                 band, bc_value, u_source, global_shape=None, sharded=False,
                 role=None, compute_dtype=None):
     """Build one fused RK-stage call; output aliased onto the last operand.
+
+    Returns ``(call, planes)``: ``planes`` is the padded z-planes the
+    call DMAs from and to HBM — each ``v`` plane of its blocks' slabs
+    once (ring), plus ``u`` rows read and output rows written.
 
     ``u_source``: where the step-input ``u`` (the ``a*u`` term) is read
     from — ``"none"`` (stage 1, a == 0), ``"operand"`` (separate input
@@ -342,7 +407,7 @@ def _make_stage(padded_shape, interior_shape, dtype, *, bz, scales, a, b,
             u_hbm = None  # "target": read from out_hbm
         if use_g:
             g_hbm, *refs = refs
-        _tgt, out_hbm, vs, *refs = refs
+        _tgt, out_hbm, ring, *refs = refs
         if use_u:
             us, *refs = refs
         else:
@@ -355,7 +420,7 @@ def _make_stage(padded_shape, interior_shape, dtype, *, bz, scales, a, b,
         sem_w, *refs = refs
         if use_g:
             (sem_gv,) = refs
-        kern(dt_ref, v_hbm, u_hbm, g_hbm, out_hbm, vs, us, res,
+        kern(dt_ref, v_hbm, u_hbm, g_hbm, out_hbm, ring, us, res,
              sem_v, sem_u, sem_w, sem_gv, offs_ref=offs_ref)
 
     n_in = (
@@ -365,7 +430,8 @@ def _make_stage(padded_shape, interior_shape, dtype, *, bz, scales, a, b,
         + (1 if use_g else 0)
         + 1  # aliased target
     )
-    scratch = [pltpu.VMEM((2, bz + 2 * R) + trailing, dtype)]
+    depth, _ = _ring_geometry(bz, n_grid)
+    scratch = [pltpu.VMEM((depth,) + trailing, dtype)]
     if use_u:
         scratch.append(pltpu.VMEM((2, bz) + trailing, dtype))
     scratch.append(pltpu.VMEM((2, bz) + trailing, dtype))
@@ -374,13 +440,15 @@ def _make_stage(padded_shape, interior_shape, dtype, *, bz, scales, a, b,
         scratch.append(pltpu.SemaphoreType.DMA((2,)))
     scratch.append(pltpu.SemaphoreType.DMA((2,)))
     if use_g:
-        scratch.append(pltpu.SemaphoreType.DMA((2,)))
+        scratch.append(pltpu.SemaphoreType.DMA((1,)))
 
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]  # dt
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * (n_in - 1)
     if sharded:
         in_specs[1] = pl.BlockSpec(memory_space=pltpu.SMEM)
 
+    rows = n_grid * bz
+    planes = rows + 2 * R + rows * (2 if use_u else 1)
     return pl.pallas_call(
         kernel,
         grid=(n_grid,),
@@ -391,7 +459,7 @@ def _make_stage(padded_shape, interior_shape, dtype, *, bz, scales, a, b,
         input_output_aliases={n_in - 1: 0},  # last operand -> out
         compiler_params=None if interpret_mode() else compiler_params(),
         interpret=interpret_mode(),
-    )
+    ), planes
 
 
 class FusedDiffusionStepper(FusedStepperBase):
@@ -425,9 +493,8 @@ class FusedDiffusionStepper(FusedStepperBase):
         # ``dtype`` — embed downcasts, extract restores (Mosaic has no
         # f64 vector path; accuracy is f32, priced in PARITY.md)
         self._storage = jnp.dtype(storage_dtype or dtype)
-        # bf16-storage rung: state/DMA at 2 B/cell (the ref-grid row is
-        # measured at 85-92% of HBM pin bandwidth — bytes are the only
-        # remaining lever, PARITY.md), arithmetic in f32
+        # bf16-storage rung: state/DMA at 2 B/cell (the stage kernel is
+        # HBM-bound, PARITY.md), arithmetic in f32
         compute_dtype = (
             jnp.float32 if self.dtype == jnp.bfloat16 else None
         )
@@ -451,7 +518,10 @@ class FusedDiffusionStepper(FusedStepperBase):
                 # stay frozen (they are neither interior nor face in the
                 # global-index masks), so interior cell nz-1 reads them
                 # as the Dirichlet ghosts it needs. Score balances z-halo
-                # amortization bz/(bz+2R) against wasted dead rows.
+                # amortization bz/(bz+2R) against wasted dead rows. The
+                # ring reads each z-halo plane once per call, so the
+                # first factor now prices only the grid's per-block
+                # overhead; the picks it makes are kept as measured.
                 def score(b):
                     blocks = -(-nz // b)
                     return (b / (b + 2 * R)) * (nz / (blocks * b))
@@ -485,18 +555,21 @@ class FusedDiffusionStepper(FusedStepperBase):
             overlap_split and self.sharded and nz // bz >= 3 and bz >= R
         )
         sources = ("none", "operand", "target")
+        plan = []  # HBM planes of every stage call one step makes
 
         def mk(role):
-            return tuple(
-                _make_stage(
+            calls = []
+            for (a, b), src in zip(_STAGES, sources):
+                call, planes = _make_stage(
                     self.padded_shape, self.interior_shape, self.dtype,
                     bz=bz, scales=scales, a=a, b=b,
                     band=band, bc_value=float(bc_value), u_source=src,
                     global_shape=self.global_shape, sharded=self.sharded,
                     role=role, compute_dtype=compute_dtype,
                 )
-                for (a, b), src in zip(_STAGES, sources)
-            )
+                calls.append(call)
+                plan.append(planes)
+            return tuple(calls)
 
         self.dt = float(dt)
 
@@ -541,6 +614,11 @@ class FusedDiffusionStepper(FusedStepperBase):
                 return S, T1, T2              # in place
 
         self._step = step
+        #: bytes the stage calls of one step DMA from and to HBM (this
+        #: shard's), summed from the block plan
+        self.hbm_bytes_per_step = sum(plan) * (
+            self.padded_shape[1] * self.padded_shape[2] * self.dtype.itemsize
+        )
 
     def embed(self, u):
         full = jnp.full(self.padded_shape, self.bc_value, self.dtype)

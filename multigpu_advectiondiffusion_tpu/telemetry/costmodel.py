@@ -36,7 +36,9 @@ f64 bytes):
   compute-only).
 * ``fused-stage``: SSP-RK3 ping-pong S/T1/T2 — stage 1 reads S writes
   T1 (2), stages 2/3 read the previous stage plus S and write (3 each)
-  = **8**.
+  = **8**. The stage kernel streams its input through a VMEM ring of
+  z-planes, so it moves these passes plus 2R halo planes per stage
+  call (``FusedDiffusionStepper.hbm_bytes_per_step`` counts them).
 * ``per-axis-pallas``: per stage, one read+write sweep per axis
   (2*ndim) plus the RK combine (read L, u_s, u0; write u = 4) =
   **3*(2*ndim+4)**.

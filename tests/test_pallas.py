@@ -323,6 +323,167 @@ def test_fused_diffusion_advance_to_matches_xla():
     assert outs["pallas"][2] == outs["xla"][2] == 5
 
 
+def _stage_stepper(solver, shape, block_z, dtype="float32", **kw):
+    """A fused-stage stepper with a forced z-block, in the solver's
+    cache slot, so ``solver.run`` marches with it."""
+    from multigpu_advectiondiffusion_tpu.ops.pallas.fused_diffusion import (
+        FusedDiffusionStepper,
+    )
+
+    assert solver._fused_stepper().engaged_label == "fused-stage"
+    fused = FusedDiffusionStepper(
+        shape, dtype, solver.grid.spacing, [1.0] * 3, solver.dt, 2, 0.0,
+        block_z=block_z, **kw,
+    )
+    solver._cache["fused"] = fused
+    return fused
+
+
+def _xla_run(grid, steps, mesh=None, decomp=None):
+    solver = DiffusionSolver(
+        DiffusionConfig(grid=grid, dtype="float32", impl="xla"),
+        mesh=mesh, decomp=decomp,
+    )
+    return np.asarray(solver.run(solver.initial_state(), steps).u)
+
+
+@pytest.mark.parametrize("nz,block_z,dtype", [
+    (9, 1, "float32"),  # one plane per block: the ring's 6 planes
+    (14, 3, "float32"),  # 2R % bz != 0: shifted ring, one dead row
+    (8, 8, "float32"),  # one block: the slab alone, no ring
+    (14, 3, "bfloat16"),  # bf16 storage through the shifted ring
+], ids=["bz1", "bz3-dead-row", "one-block", "bf16-bz3"])
+def test_fused_diffusion_ring_blocks_match_xla(nz, block_z, dtype):
+    """The stage kernel streams each ``v`` plane through its VMEM ring
+    once, whatever the block: a plane per block, a block that does not
+    divide 2R (shifted ring, dead tail row) and a single block all march
+    as the generic XLA path does; bf16 storage stays within its storage
+    price."""
+    grid = Grid.make(24, 16, nz, lengths=2.0)
+    solver = DiffusionSolver(
+        DiffusionConfig(grid=grid, dtype=dtype, impl="pallas_stage")
+    )
+    fused = _stage_stepper(solver, grid.shape, block_z, dtype)
+    nz_eff = fused.padded_shape[0] - 2 * fused.halo
+    assert nz_eff == -(-nz // block_z) * block_z
+    got = np.asarray(solver.run(solver.initial_state(), 6).u, np.float32)
+    ref = _xla_run(grid, 6)
+    scale = float(np.max(np.abs(ref)))
+    if dtype == "bfloat16":
+        # the bound of test_fused_diffusion_bf16_storage_rung
+        assert float(np.max(np.abs(got - ref))) <= 0.05 * scale
+    else:
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=2e-6 * scale)
+
+
+@pytest.mark.parametrize("overlap", ["padded", "split"])
+def test_fused_diffusion_sharded_ring_blocks_match_xla(devices, overlap):
+    """dz=2 shards of 30 planes in 5-plane blocks (a shifted ring): the
+    serialized-refresh calls ring over all six blocks, the split
+    schedule's interior call over its four, and its edge calls read
+    their ghost planes from the exchanged slabs."""
+    from multigpu_advectiondiffusion_tpu.parallel.mesh import (
+        Decomposition,
+        make_mesh,
+    )
+
+    grid = Grid.make(24, 16, 60, lengths=2.0)
+    mesh, decomp = make_mesh({"dz": 2}), Decomposition.slab("dz")
+    solver = DiffusionSolver(
+        DiffusionConfig(grid=grid, dtype="float32", impl="pallas_stage",
+                        overlap=overlap),
+        mesh=mesh, decomp=decomp,
+    )
+    fused = _stage_stepper(solver, (30, 16, 24), 5,
+                           global_shape=grid.shape,
+                           overlap_split=overlap == "split")
+    assert fused.sharded and fused.overlap_split == (overlap == "split")
+    got = np.asarray(solver.run(solver.initial_state(), 5).u)
+    ref = _xla_run(grid, 5)
+    scale = float(np.max(np.abs(ref)))
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=2e-6 * scale)
+
+
+@pytest.mark.parametrize("nz,block_z,split", [
+    (9, 1, False), (14, 3, False), (8, 8, False), (30, 5, True),
+], ids=["bz1", "bz3", "one-block", "split-bz5"])
+def test_fused_diffusion_stage_fetches_each_plane_once(
+    devices, monkeypatch, nz, block_z, split
+):
+    """Counted DMA by DMA while one step runs (interpret mode): every
+    stage call fetches each plane of its ``v`` slab range from HBM once
+    — ``nz_eff + 2R`` planes for a whole-range call, plus the 2R ghost
+    planes each edge call of the split schedule takes from the exchanged
+    slabs — and the stepper's ``hbm_bytes_per_step`` is the sum of every
+    DMA to or from HBM, ``u`` reads and output writes included."""
+    import collections
+
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    planes = collections.Counter()
+    real = pltpu.make_async_copy
+
+    def space(ref):
+        return str(getattr(ref, "ref", ref).aval.memory_space)
+
+    def counted(src, dst, sem):
+        copy = real(src, dst, sem)
+        root = getattr(dst, "ref", dst)
+        kind = ("write" if space(dst) == "any"
+                else "v" if root.ndim == 3 else "u")
+        n = src.shape[0]
+
+        class Counted:
+            def start(self):
+                jax.debug.callback(lambda: planes.update({kind: n}))
+                copy.start()
+
+            def wait(self):
+                copy.wait()
+
+        return Counted()
+
+    monkeypatch.setattr(pltpu, "make_async_copy", counted)
+    shards = 2 if split else 1
+    shape = (nz, 16, 24)
+    kw = {}
+    if split:
+        from multigpu_advectiondiffusion_tpu.parallel.mesh import (
+            Decomposition,
+            make_mesh,
+        )
+
+        grid = Grid.make(24, 16, nz * shards, lengths=2.0)
+        kw = {"mesh": make_mesh({"dz": shards}),
+              "decomp": Decomposition.slab("dz")}
+    else:
+        grid = Grid.make(24, 16, nz, lengths=2.0)
+    solver = DiffusionSolver(
+        DiffusionConfig(grid=grid, dtype="float32", impl="pallas_stage",
+                        overlap="split" if split else "padded"),
+        **kw,
+    )
+    fused = _stage_stepper(
+        solver, shape, block_z,
+        **({"global_shape": grid.shape, "overlap_split": True}
+           if split else {}),
+    )
+    assert fused.overlap_split == split
+    solver.run(solver.initial_state(), 1).u.block_until_ready()
+
+    R = fused.halo
+    nz_eff = fused.padded_shape[0] - 2 * R
+    ghost = 4 * R if split else 0  # two edge calls' own 2R slabs
+    assert planes["v"] == shards * 3 * (nz_eff + 2 * R + ghost)
+    assert planes["u"] == shards * 2 * nz_eff
+    assert planes["write"] == shards * 3 * nz_eff
+    plane_bytes = (fused.padded_shape[1] * fused.padded_shape[2]
+                   * fused.dtype.itemsize)
+    assert fused.hbm_bytes_per_step == (
+        sum(planes.values()) // shards * plane_bytes)
+
+
 def test_fused_diffusion_split_overlap_matches_serialized(devices):
     """overlap='split' diffusion on a z-slab mesh runs the three-call
     overlapped schedule (interior blocks concurrent with the z-halo

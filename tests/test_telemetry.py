@@ -210,6 +210,25 @@ def test_every_public_driver_opens_one_dispatch_span(tmp_path, op):
     assert any(n.startswith(f"tpucfd.{op}[") for n in children)
 
 
+@pytest.mark.parametrize("impl,stepper", [("pallas_stage", "fused-stage"),
+                                          ("xla", "generic-xla")])
+def test_run_span_carries_the_stage_plan(tmp_path, impl, stepper):
+    """``tpucfd.run[<stepper>]`` carries the engaged stepper's
+    ``hbm_bytes_per_step`` (its block plan, a host int) where the
+    stepper declares one; the generic path declares none."""
+    solver = _diffusion3d_small(impl=impl)
+    state = solver.run(solver.initial_state(), 1)
+    events = host_events(
+        tmp_path, lambda: solver.run(state, 1).u.block_until_ready())
+    (span,) = [e for e in events if e.name == f"tpucfd.run[{stepper}]"]
+    plan = solver.engaged_path().get("hbm_bytes_per_step")
+    if impl == "xla":
+        assert plan is None and span.stats == {}
+    else:
+        assert plan == solver._fused_stepper().hbm_bytes_per_step > 0
+        assert span.stats == {"hbm_bytes_per_step": plan}
+
+
 # --------------------------------------------------------------------- #
 # Event sink
 # --------------------------------------------------------------------- #

@@ -257,6 +257,31 @@ def test_fused_split_overlap_hides_collectives(topo, mosaic, model):
                                     solver.sharding()))
 
 
+def test_fused_stage_compiles_at_dz4_shard_size(topo, mosaic):
+    """The benchmark's 1024^3 ``dz=4`` march: each chip's 256x1024x1024
+    shard runs the fused-stage rung one 4.76 MB plane per block (bz=1),
+    its ``v`` planes streaming through a six-plane VMEM ring. A VMEM
+    overrun or a lowering refusal at that plane size fails here."""
+    grid = Grid.make(1024, 1024, 1024, lengths=10.0)
+    solver = DiffusionSolver(
+        DiffusionConfig(grid=grid, dtype="float32", impl="pallas",
+                        overlap="split"),
+        mesh=Mesh(np.asarray(topo.devices[:4]), ("dz",)),
+        decomp=Decomposition.slab("dz"),
+    )
+    fused = solver._fused_stepper()
+    assert fused.engaged_label == "fused-stage"
+    assert fused.padded_shape == (260, 1032, 1152)
+    assert not fused.overlap_split  # bz = 1 < R: serialized refresh
+    refresh, offsets_fn, _ = solver._fused_sharded_ctx(fused)
+
+    def block(u, t):
+        return fused.run(u, t, 1, refresh=refresh, offsets=offsets_fn())
+
+    txt = _compile(solver._wrap(block), grid, solver.sharding())
+    assert txt.count("tpu_custom_call") >= 3, "stage kernels not lowered"
+
+
 def test_dma_whole_run_compiles(topo, mosaic, monkeypatch):
     """The in-kernel remote-DMA rung on ``dz=4``: one Pallas program per
     shard whose halo moves by ``make_async_remote_copy`` behind a
