@@ -35,6 +35,7 @@ from multigpu_advectiondiffusion_tpu.models.state import (
     EnsembleState,
     SolverState,
 )
+from multigpu_advectiondiffusion_tpu.ops.pallas.stepper_base import PLAN_KEYS
 from multigpu_advectiondiffusion_tpu.ops.stencils import Padder
 from multigpu_advectiondiffusion_tpu.ops.stencils import slice_axis
 from multigpu_advectiondiffusion_tpu.parallel.halo import (
@@ -650,14 +651,13 @@ class SolverBase:
         over the whole rung hierarchy) plus, when a telemetry sink is
         installed, a structured ``solver.<op>`` span carrying the
         engaged stepper/impl/overlap. The profiler span takes the
-        stepper's ``hbm_bytes_per_step`` as a stat where it declares
-        one (:meth:`engaged_path`)."""
+        stepper's ``hbm_bytes_per_step``, ``halo_bytes_per_step`` and
+        ``block`` as stats where it declares them (:meth:`engaged_path`)."""
         from multigpu_advectiondiffusion_tpu import telemetry
 
         with annotate(RESOLVE_SPAN):
             eng = self.engaged_path(mode=mode)
-        plan = eng.get("hbm_bytes_per_step")
-        stats = {} if plan is None else {"hbm_bytes_per_step": plan}
+        stats = {k: eng[k] for k in PLAN_KEYS if k in eng}
         stack = contextlib.ExitStack()
         stack.enter_context(
             annotate(f"tpucfd.{op}[{eng['stepper']}]", **stats)
@@ -860,8 +860,12 @@ class SolverBase:
         and — when the kernel ladder degraded after a Mosaic/Pallas
         dispatch failure — ``degraded``, the downgrade event list
         (from/to rung + failure text); absent on healthy runs. A fused
-        stepper that declares its plan adds ``hbm_bytes_per_step``: the
-        bytes its kernels DMA from and to HBM per step on one shard.
+        stepper that declares its plan adds ``hbm_bytes_per_step`` (the
+        bytes its kernels DMA from and to HBM per step on one shard),
+        ``halo_bytes_per_step`` (the bytes one shard sends through the
+        halo exchange per step, when sharded) and ``block`` (the kernel
+        block that runs, joined: ``"8x8"`` for Burgers' ``(bz, by)``,
+        ``"19"`` for diffusion's ``(bz,)``).
 
         ``mode`` mirrors the execution dispatch: ``"t_end"`` engages the
         fused stepper only when it has ``run_to`` (``advance_to``'s extra
@@ -917,9 +921,9 @@ class SolverBase:
                 "precision": self._precision_mode(),
                 "fallback": None,
             }
-            plan = getattr(fused, "hbm_bytes_per_step", None)
+            plan = getattr(fused, "plan", None)  # FusedStepperBase's
             if plan is not None:
-                out["hbm_bytes_per_step"] = plan
+                out.update(plan())
             if self._tuned is not None:
                 out["tuned"] = self._tuned_summary()
             if self._degrade_events:

@@ -688,7 +688,8 @@ def _stage_kernel(
 def _make_stage(padded_shape, local_shape, dtype, *, bz, by, inv_dx,
                 nu_scales, flux, variant, a, b, u_source, role=None,
                 emit_max=False, order=5, r=R, x0=0, x_ghosts=False):
-    """One fused RK-stage call; output aliased onto the last operand.
+    """One fused RK-stage call, output aliased onto the last operand, and
+    the bytes it DMAs from and to HBM: ``(call, nbytes)``.
 
     ``u_source``: ``"none"`` / ``"operand"`` / ``"target"`` (in-place
     final stage), as in ``fused_diffusion._make_stage``. Operands:
@@ -812,6 +813,16 @@ def _make_stage(padded_shape, local_shape, dtype, *, bz, by, inv_dx,
         out_specs = (out_specs, pl.BlockSpec(memory_space=pltpu.SMEM))
         out_shape = (out_shape, jax.ShapeDtypeStruct((1,), jnp.float32))
 
+    # px-lane rows the call DMAs from and to HBM: each block's v box,
+    # u core (stages 2 and 3) and core write; the edge y-blocks' margin
+    # writes; a full-range call's z edge rows
+    rows = n_bz_grid * n_by * ((bz + 2 * r) * yb
+                               + bz * by * (2 if use_u else 1))
+    rows += n_bz_grid * 2 * bz * MARGIN
+    if z_edge:
+        rows += 2 * n_by * r * by
+    nbytes = rows * px * jnp.dtype(dtype).itemsize
+
     return pl.pallas_call(
         kernel,
         grid=(n_bz_grid, n_by),
@@ -822,7 +833,7 @@ def _make_stage(padded_shape, local_shape, dtype, *, bz, by, inv_dx,
         input_output_aliases={n_in - 1: 0},  # last operand -> out
         compiler_params=None if interpret_mode() else compiler_params(),
         interpret=interpret_mode(),
-    )
+    ), nbytes
 
 
 class FusedBurgersStepper(FusedStepperBase):
@@ -919,9 +930,12 @@ class FusedBurgersStepper(FusedStepperBase):
         self._dt_from_max = dt_from_max
         self._wave_fn = wave_fn
 
+        plan = []  # HBM bytes of every stage call one step makes
+
         def mk(role):
-            return tuple(
-                _make_stage(
+            calls = []
+            for (a, b), src in zip(_STAGES, sources):
+                call, nbytes = _make_stage(
                     self.padded_shape, self.interior_shape, self.dtype,
                     bz=bz, by=by, inv_dx=inv_dx, nu_scales=nu_scales,
                     flux=flux, variant=variant, a=a, b=b, u_source=src,
@@ -931,8 +945,9 @@ class FusedBurgersStepper(FusedStepperBase):
                     # schedule's three calls each fold their own blocks
                     emit_max=(self._emit_max and src == "target"),
                 )
-                for (a, b), src in zip(_STAGES, sources)
-            )
+                calls.append(call)
+                plan.append(nbytes)
+            return tuple(calls)
 
         self.dt = None if dt is None else float(dt)
         self._dt_fn = dt_fn
@@ -1003,6 +1018,9 @@ class FusedBurgersStepper(FusedStepperBase):
                     return S, T1, T2
 
         self._step = step
+        #: bytes the stage calls of one step DMA from and to HBM (this
+        #: shard's), summed from the block plan
+        self.hbm_bytes_per_step = sum(plan)
 
     @staticmethod
     def supported(interior_shape, dtype, y_sharded: bool = False,

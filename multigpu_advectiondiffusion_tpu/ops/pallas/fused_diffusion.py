@@ -534,6 +534,7 @@ class FusedDiffusionStepper(FusedStepperBase):
                 "the exchanged core"
             )
         bz = block_z
+        self.block = (bz,)
         # nz rounded up to a block multiple (== nz when sharded: both
         # branches above guarantee an exact divisor there)
         nz_eff = -(-nz // bz) * bz

@@ -28,6 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from multigpu_advectiondiffusion_tpu.parallel.halo import exchange_bytes
+
+#: what a fused stepper declares of its plan (:meth:`FusedStepperBase.plan`)
+PLAN_KEYS = ("hbm_bytes_per_step", "halo_bytes_per_step", "block")
+
 
 def chunk_counts(num_iters: int, steps_per_exchange: int):
     """``(full_blocks, remainder)`` of the communication-avoiding k-step
@@ -85,6 +90,40 @@ class FusedStepperBase:
     #: silent corruption (a neighbor push landing over rows the
     #: consumer already read).
     remote_dma = None
+    #: the engaged kernel block, ``(bz, by)`` / ``(bz,)``, where a
+    #: stepper sets it
+    block = None
+    #: bytes its kernels DMA from and to HBM per step, where a stepper
+    #: counts them from its block plan
+    hbm_bytes_per_step = None
+
+    def plan(self) -> dict:
+        """What the stepper declares of its plan, as ``engaged_path()``
+        and the ``tpucfd.<op>[<stepper>]`` span report it:
+        ``hbm_bytes_per_step``, ``halo_bytes_per_step`` and ``block``
+        (joined, as ``"8x8"``), each where declared."""
+        out = {k: getattr(self, k) for k in PLAN_KEYS}
+        if self.block is not None:
+            out["block"] = "x".join(str(b) for b in self.block)
+        return {k: v for k, v in out.items() if v is not None}
+
+    @property
+    def halo_bytes_per_step(self):
+        """Bytes this shard sends through the halo exchange per step, or
+        ``None`` unsharded: one exchange of the padded buffer on every
+        sharded axis after each of the three SSP-RK3 stages, counted by
+        ``parallel.halo.exchange_bytes`` as its
+        ``halo.bytes_per_execution`` counter counts it."""
+        if not self.sharded:
+            return None
+        depth = int(getattr(self, "exchange_depth", self.halo))
+        item = jnp.dtype(self.dtype).itemsize
+        return 3 * sum(
+            exchange_bytes(self.padded_shape, ax, depth, item)
+            for ax, (g, n) in enumerate(zip(self.global_shape,
+                                            self.interior_shape))
+            if g != n
+        )
 
     def stencil_spec(self) -> dict:
         """Queryable stencil/halo metadata — the ``R = 3``-style radius

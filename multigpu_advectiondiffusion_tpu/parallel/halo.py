@@ -17,6 +17,7 @@ Two deliberate upgrades over the reference (SURVEY §2.1.5, §3.2):
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Sequence
 
 import jax
@@ -179,6 +180,13 @@ def exchange_ghosts(
         return from_left, from_right
 
 
+def exchange_bytes(shape, axis: int, halo: int, itemsize: int) -> int:
+    """Bytes one exchange on ``axis`` hands to ``ppermute`` from a shard
+    of ``shape``: two ``halo``-deep slabs (lo + hi), one to each
+    neighbour."""
+    return 2 * halo * math.prod(shape) // shape[axis] * itemsize
+
+
 def _record_exchange(
     u, axis: int, halo: int, mesh_axis: str, repeats: int = 1,
     wire_dtype=None,
@@ -200,15 +208,12 @@ def _record_exchange(
     sink = telemetry.get_sink()
     if not sink.active:
         return
-    slab = 1
-    for ax, n in enumerate(u.shape):
-        slab *= halo if ax == axis else int(n)
     # wire_dtype: the bf16-storage rung moves ghost slabs down-cast on
     # the wire — the payload is the wire dtype's itemsize, not the
     # resident block's (ISSUE 16)
     item = jnp.dtype(wire_dtype if wire_dtype is not None
                      else u.dtype).itemsize
-    nbytes = 2 * slab * item
+    nbytes = exchange_bytes(u.shape, axis, halo, item)
     sink.counter(
         "halo.exchanges_traced", 1, axis=axis, mesh_axis=mesh_axis
     )

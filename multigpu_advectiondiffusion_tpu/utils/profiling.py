@@ -13,9 +13,10 @@ early against it, so compare host times with host times:
   the call fixes them), ``programs`` and ``cache`` (:class:`dispatch`);
   its children ``tpucfd.dispatch.resolve`` (rung, stepper, halo context
   and dispatch-cache lookup), ``tpucfd.<op>[<stepper>]`` (the call's
-  body under the engaged rung; stat ``hbm_bytes_per_step``, the
-  engaged stepper's planned HBM traffic, where it declares one),
-  ``tpucfd.dispatch.execute`` (the
+  body under the engaged rung; stats ``hbm_bytes_per_step``, the
+  engaged stepper's planned HBM traffic, ``halo_bytes_per_step``, what
+  a shard sends through the halo exchange, and ``block``, its kernel
+  block, where it declares them), ``tpucfd.dispatch.execute`` (the
   compiled program's call, which returns before the device runs it) and
   ``tpucfd.dispatch.state`` (the returned state, its ``it`` update);
 * ``tpucfd.compile`` (stat ``key``) around a program's first-call

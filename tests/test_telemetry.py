@@ -214,8 +214,9 @@ def test_every_public_driver_opens_one_dispatch_span(tmp_path, op):
                                           ("xla", "generic-xla")])
 def test_run_span_carries_the_stage_plan(tmp_path, impl, stepper):
     """``tpucfd.run[<stepper>]`` carries the engaged stepper's
-    ``hbm_bytes_per_step`` (its block plan, a host int) where the
-    stepper declares one; the generic path declares none."""
+    ``hbm_bytes_per_step`` (its block plan, a host int) and its
+    ``block`` where the stepper declares them (unsharded, no
+    ``halo_bytes_per_step``); the generic path declares none."""
     solver = _diffusion3d_small(impl=impl)
     state = solver.run(solver.initial_state(), 1)
     events = host_events(
@@ -225,8 +226,12 @@ def test_run_span_carries_the_stage_plan(tmp_path, impl, stepper):
     if impl == "xla":
         assert plan is None and span.stats == {}
     else:
-        assert plan == solver._fused_stepper().hbm_bytes_per_step > 0
-        assert span.stats == {"hbm_bytes_per_step": plan}
+        fused = solver._fused_stepper()
+        assert plan == fused.hbm_bytes_per_step > 0
+        assert fused.halo_bytes_per_step is None
+        (bz,) = fused.block
+        # the profiler reads a numeric string stat back as a number
+        assert span.stats == {"hbm_bytes_per_step": plan, "block": bz}
 
 
 # --------------------------------------------------------------------- #
