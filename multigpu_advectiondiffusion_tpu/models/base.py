@@ -651,8 +651,9 @@ class SolverBase:
         over the whole rung hierarchy) plus, when a telemetry sink is
         installed, a structured ``solver.<op>`` span carrying the
         engaged stepper/impl/overlap. The profiler span takes the
-        stepper's ``hbm_bytes_per_step``, ``halo_bytes_per_step`` and
-        ``block`` as stats where it declares them (:meth:`engaged_path`)."""
+        stepper's plan (``hbm_bytes_per_step``, ``halo_bytes_per_step``,
+        ``block``, ``y_dead_rows``) as stats where it declares them
+        (:meth:`engaged_path`)."""
         from multigpu_advectiondiffusion_tpu import telemetry
 
         with annotate(RESOLVE_SPAN):
@@ -863,9 +864,11 @@ class SolverBase:
         stepper that declares its plan adds ``hbm_bytes_per_step`` (the
         bytes its kernels DMA from and to HBM per step on one shard),
         ``halo_bytes_per_step`` (the bytes one shard sends through the
-        halo exchange per step, when sharded) and ``block`` (the kernel
-        block that runs, joined: ``"8x8"`` for Burgers' ``(bz, by)``,
-        ``"19"`` for diffusion's ``(bz,)``).
+        halo exchange per step, when sharded), ``block`` (the kernel
+        block that runs, joined: ``"8x64"`` for Burgers' ``(bz, by)``,
+        ``"19"`` for diffusion's ``(bz,)``) and ``y_dead_rows`` (the y
+        rows Burgers' kernels work past the plane, rounding it up to
+        whole blocks).
 
         ``mode`` mirrors the execution dispatch: ``"t_end"`` engages the
         fused stepper only when it has ``run_to`` (``advance_to``'s extra

@@ -178,9 +178,12 @@ def _live_bytes(bz: int, by: int, lx: int, itemsize: int,
     return 5 * slab + (18 if order == 5 else 24) * core
 
 
-def _pick_blocks(nz, ny, lx, itemsize, r: int = R, order: int = 5,
+def _pick_blocks(nz, ly, lx, itemsize, r: int = R, order: int = 5,
                  x_ghosts: bool = False):
-    """First viable block in measured-preference order.
+    """The widest viable ``(bz, by)`` whose ``by`` tiles the plane's
+    rows, ``round_up(ly, 8)``: ``by`` the largest multiple of 8 that
+    divides them up to the order's measured best height, ``bz`` the
+    largest of 8 down to 1 that divides ``nz`` within the VMEM budget.
 
     v5e, 512^3 (lane-aligned layout, roll-based y sweep), order 5:
     (8,64) 9491 MLUPS > (16,32) 9378 > (8,16)/(16,16) ~8877 > (16,64)
@@ -188,12 +191,22 @@ def _pick_blocks(nz, ny, lx, itemsize, r: int = R, order: int = 5,
     scheduling than the halo amortization returns. Order 7 (halo 4, 6
     e-windows per sweep side live) peaks one size smaller — (8,32) 5247
     > (4,64) 5206 > (8,16) 5047 > (16,64) 5044 > (8,128) 4988 > (8,64)
-    4553 (out/weno7_block_exp.py sweeps) — so its y preference leads
-    with 32.
+    4553 (out/weno7_block_exp.py sweeps) — so its height is 32.
+
+    The height need not be a power of two. v5e, the four-chip Burgers
+    shard (1000x1000x200 local, inviscid, split roles,
+    ``out/weno_yblock_exp.py``), ms a step at bz = 8, y worked on the
+    rows in brackets: by=8 66.24 [1000] > 64 66.94 [1024] > 72 64.91
+    [1008] > 16 60.74 [1008] > 32 57.93 [1024] > 24 57.67 [1008] > 48
+    55.78 [1008] > 56 55.71 [1008] > 40 55.68 [1000]. So 1,000 rows run
+    (8,40), 1.19x faster than the (8,8) that powers of two left them;
+    rounding the rows up to a wider block buys nothing more, and at
+    1,024 lanes (8,64) and (8,72) fall off the working-set cliff that
+    (16,64) meets at 512^3.
     """
-    by_pref = (64, 128, 32, 16, 8) if order == 5 else (32, 64, 16, 128, 8)
-    for by in by_pref:
-        if ny % by:
+    rows = round_up(ly, SUBLANE)
+    for by in range(64 if order == 5 else 32, 0, -SUBLANE):
+        if rows % by:
             continue
         for bz in (8, 7, 6, 5, 4, 3, 2, 1):
             if nz % bz:
@@ -887,21 +900,24 @@ class FusedBurgersStepper(FusedStepperBase):
             raise ValueError(
                 f"y-sharded fused Burgers needs ly % {SUBLANE} == 0, got {ly}"
             )
-        ly_eff = round_up(ly, SUBLANE)
+        self.dtype = jnp.dtype(dtype)
+        blk = block if block is not None else _pick_blocks(
+            lz, ly, lx, self.dtype.itemsize, r, order, self.x_sharded
+        )
+        if (blk is None or lz % blk[0] or blk[1] % SUBLANE
+                or (y_sharded and ly % blk[1])):
+            raise ValueError(
+                f"no viable (bz, by) block for interior {interior_shape}"
+            )
+        bz, by = blk
+        # the kernel works y in whole blocks: rows past ly are dead
+        ly_eff = round_up(ly, by)
+        self.y_dead_rows = ly_eff - ly
         self.padded_shape = (
             lz + 2 * r,
             ly_eff + 2 * MARGIN,
             _x_widths(lx, r, self.x_sharded)[0],
         )
-        self.dtype = jnp.dtype(dtype)
-        blk = block if block is not None else _pick_blocks(
-            lz, ly_eff, lx, self.dtype.itemsize, r, order, self.x_sharded
-        )
-        if blk is None or lz % blk[0] or ly_eff % blk[1] or blk[1] % 8:
-            raise ValueError(
-                f"no viable (bz, by) block for interior {interior_shape}"
-            )
-        bz, by = blk
         inv_dx = [1.0 / spacing[i] for i in range(3)]
         nu_scales = None
         if nu:
@@ -1028,9 +1044,8 @@ class FusedBurgersStepper(FusedStepperBase):
         lz, ly, lx = interior_shape
         if y_sharded and ly % SUBLANE:
             return False
-        ly_eff = round_up(ly, SUBLANE)
         return (
-            _pick_blocks(lz, ly_eff, lx, jnp.dtype(dtype).itemsize,
+            _pick_blocks(lz, ly, lx, jnp.dtype(dtype).itemsize,
                          HALO[order], order, x_sharded)
             is not None
         )

@@ -31,7 +31,8 @@ from jax import lax
 from multigpu_advectiondiffusion_tpu.parallel.halo import exchange_bytes
 
 #: what a fused stepper declares of its plan (:meth:`FusedStepperBase.plan`)
-PLAN_KEYS = ("hbm_bytes_per_step", "halo_bytes_per_step", "block")
+PLAN_KEYS = ("hbm_bytes_per_step", "halo_bytes_per_step", "block",
+             "y_dead_rows")
 
 
 def chunk_counts(num_iters: int, steps_per_exchange: int):
@@ -96,12 +97,16 @@ class FusedStepperBase:
     #: bytes its kernels DMA from and to HBM per step, where a stepper
     #: counts them from its block plan
     hbm_bytes_per_step = None
+    #: y rows its kernels work that hold no cell (the plane height
+    #: rounded up to whole y blocks), where a stepper rounds y
+    y_dead_rows = None
 
     def plan(self) -> dict:
         """What the stepper declares of its plan, as ``engaged_path()``
         and the ``tpucfd.<op>[<stepper>]`` span report it:
-        ``hbm_bytes_per_step``, ``halo_bytes_per_step`` and ``block``
-        (joined, as ``"8x8"``), each where declared."""
+        ``hbm_bytes_per_step``, ``halo_bytes_per_step``, ``block``
+        (joined, as ``"8x8"``) and ``y_dead_rows``, each where
+        declared."""
         out = {k: getattr(self, k) for k in PLAN_KEYS}
         if self.block is not None:
             out["block"] = "x".join(str(b) for b in self.block)

@@ -15,8 +15,9 @@ early against it, so compare host times with host times:
   and dispatch-cache lookup), ``tpucfd.<op>[<stepper>]`` (the call's
   body under the engaged rung; stats ``hbm_bytes_per_step``, the
   engaged stepper's planned HBM traffic, ``halo_bytes_per_step``, what
-  a shard sends through the halo exchange, and ``block``, its kernel
-  block, where it declares them), ``tpucfd.dispatch.execute`` (the
+  a shard sends through the halo exchange, ``block``, its kernel
+  block, and ``y_dead_rows``, the y rows its kernels work past the
+  plane, where it declares them), ``tpucfd.dispatch.execute`` (the
   compiled program's call, which returns before the device runs it) and
   ``tpucfd.dispatch.state`` (the returned state, its ``it`` update);
 * ``tpucfd.compile`` (stat ``key``) around a program's first-call

@@ -75,8 +75,8 @@ def _one_step(tmp_path, solver):
 
 def test_sharded_burgers_plan(tmp_path, monkeypatch, devices):
     """The four-chip Burgers cell's schedule at a tiny shape: 24-plane
-    shards of 24x16 planes take 8x8 blocks (24 rows divide by no wider
-    y block) in three z-blocks, so the split-overlap schedule runs."""
+    shards of 24x16 planes take 8x24 blocks (one y block, 24 rows wide)
+    in three z-blocks, so the split-overlap schedule runs."""
     moved = _counted_dmas(monkeypatch)
     grid = Grid.make(16, 24, 96, lengths=(2.0, 3.0, 12.0))
     solver = BurgersSolver(
@@ -87,15 +87,16 @@ def test_sharded_burgers_plan(tmp_path, monkeypatch, devices):
     assert eng["stepper"] == "fused-stage" and eng["overlap"] == "split"
     fused = solver._fused_stepper()
     assert fused.padded_shape == (30, 40, 128)
-    assert fused.block == (8, 8) and eng["block"] == "8x8"
-    # per call: each block's v box of (8 + 2x3) x (8 + 2x8) rows, its u
-    # core (stages 2 and 3) and core write of 8x8 rows; the margin
-    # writes of the first and last y-blocks, 2 x 8x8 rows per z-block.
-    # Three calls per stage (interior, bottom, top), one z-block and
-    # three y-blocks each; every row is 128 lanes of f32
-    box, core, margins = 14 * 24, 8 * 8, 2 * 8 * 8
-    stage1 = 3 * (3 * (box + core) + margins)
-    stage23 = 3 * (3 * (box + 2 * core) + margins)
+    assert fused.block == (8, 24) and eng["block"] == "8x24"
+    # per call: the block's v box of (8 + 2x3) x (24 + 2x8) rows, its u
+    # core (stages 2 and 3) and core write of 8x24 rows; the margin
+    # writes of the first and last y-blocks (here the one block), 2 x
+    # 8x8 rows per z-block. Three calls per stage (interior, bottom,
+    # top), one z-block and one y-block each; every row is 128 lanes of
+    # f32
+    box, core, margins = 14 * 40, 8 * 24, 2 * 8 * 8
+    stage1 = 3 * (box + core + margins)
+    stage23 = 3 * (box + 2 * core + margins)
     plan = (stage1 + 2 * stage23) * 128 * F32
     assert eng["hbm_bytes_per_step"] == fused.hbm_bytes_per_step == plan
     # each stage sends 3 planes of 40x128 to each z-neighbour
@@ -105,8 +106,10 @@ def test_sharded_burgers_plan(tmp_path, monkeypatch, devices):
     stats, recorded = _one_step(tmp_path, solver)
     assert sum(moved) == 4 * plan  # what the four shards' kernels moved
     assert recorded == halo  # the exchange sites' own counter
+    assert eng["y_dead_rows"] == 0
     assert stats == {"hbm_bytes_per_step": plan,
-                     "halo_bytes_per_step": halo, "block": "8x8"}
+                     "halo_bytes_per_step": halo, "block": "8x24",
+                     "y_dead_rows": 0}
 
 
 def test_sharded_diffusion_plan(tmp_path, devices):
